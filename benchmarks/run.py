@@ -1552,6 +1552,8 @@ BENCHES = {
 
 
 def main() -> None:
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     only = [a for a in sys.argv[1:] if not a.startswith("-")]
     names = only or list(BENCHES)
     print("name,us_per_call,derived")

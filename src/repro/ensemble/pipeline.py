@@ -31,8 +31,9 @@ DEFAULT = ("affirmative", "wbf")
 
 def resolve_use_kernel(use_kernel: Union[bool, str]) -> bool:
     """``"auto"`` -> Pallas IoU kernel on accelerator backends, numpy twin
-    on CPU (where interpret-mode Pallas is orders of magnitude slower and
-    the numpy reference is the kernel's bitwise oracle anyway)."""
+    on CPU (where interpret-mode Pallas is orders of magnitude slower).
+    The two are not bit-identical: on a TPU v5e their IoU entries differed
+    by up to 2^-23 (``docs/architecture.md``, "Bit-parity contracts")."""
     if isinstance(use_kernel, str):
         if use_kernel != "auto":
             # a typo like "atuo" must not silently coerce to True (any
@@ -127,18 +128,15 @@ def batch_iou_matrices(boxes_list: Sequence[np.ndarray], *,
         return []
     if resolve_use_kernel(use_kernel):
         import jax
-        import jax.numpy as jnp
-        from repro.kernels.iou_matrix.kernel import iou_matrix_pallas
+        from repro.kernels.iou_matrix.ops import batch_self_iou
         nmax = max(int(b.shape[0]) for b in boxes_list)
         if nmax == 0:
             return [np.zeros((0, 0), np.float32) for _ in boxes_list]
         padded = np.zeros((len(boxes_list), nmax, 4), np.float32)
         for i, b in enumerate(boxes_list):
             padded[i, :len(b)] = b
-        interpret = jax.default_backend() == "cpu"
-        full = jax.vmap(lambda b: iou_matrix_pallas(
-            b, b, interpret=interpret))(jnp.asarray(padded))
-        full = np.asarray(full)
+        full = np.asarray(batch_self_iou(
+            padded, interpret=jax.default_backend() == "cpu"))
         return [full[i, :len(b), :len(b)] for i, b in enumerate(boxes_list)]
     return [iou_matrix(b, b) if len(b) else np.zeros((0, 0), np.float32)
             for b in boxes_list]

@@ -1,16 +1,12 @@
 """Public wrapper: picks Pallas-on-TPU or interpret-on-CPU automatically."""
 from __future__ import annotations
 
-import warnings
+import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels.iou_matrix.kernel import iou_matrix_pallas
-
-# one-time flag: the numpy-twin fallback warns on its first use only
-_FALLBACK_WARNED = False
 
 
 def iou_matrix_op(boxes_a, boxes_b, *, block_m: int = 128,
@@ -18,28 +14,23 @@ def iou_matrix_op(boxes_a, boxes_b, *, block_m: int = 128,
     """(M,4) x (N,4) -> (M,N) IoU via the Pallas kernel (interpret on CPU).
 
     Block sizes are clamped to the input sizes (a 128-wide block over a
-    3-box input is a lowering error on real backends), and any exception
-    out of kernel lowering/execution falls back to the numpy twin — the
-    kernel's bitwise oracle — with a one-time warning.
+    3-box input is a lowering error on real backends).  A kernel that
+    fails to lower or run raises: a measuring path must never move onto
+    the host behind the caller's back.
     """
-    global _FALLBACK_WARNED
     a = jnp.asarray(boxes_a, jnp.float32).reshape(-1, 4)
     b = jnp.asarray(boxes_b, jnp.float32).reshape(-1, 4)
     M, N = int(a.shape[0]), int(b.shape[0])
     if M == 0 or N == 0:
         return jnp.zeros((M, N), jnp.float32)
     interpret = jax.default_backend() == "cpu"
-    bm, bn = min(block_m, M), min(block_n, N)
-    try:
-        return iou_matrix_pallas(a, b, block_m=bm, block_n=bn,
-                                 interpret=interpret)
-    except Exception as e:  # lowering/unsupported-backend failures
-        if not _FALLBACK_WARNED:
-            _FALLBACK_WARNED = True
-            warnings.warn(
-                "Pallas IoU kernel failed to lower/run "
-                f"({type(e).__name__}: {e}); falling back to the numpy "
-                "twin for this process", RuntimeWarning, stacklevel=2)
-        from repro.ensemble.boxes import iou_matrix
-        return jnp.asarray(iou_matrix(np.asarray(a), np.asarray(b)),
-                           jnp.float32)
+    return iou_matrix_pallas(a, b, block_m=min(block_m, M),
+                             block_n=min(block_n, N), interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def batch_self_iou(boxes, *, interpret: bool = False) -> jnp.ndarray:
+    """(B, M, 4) -> (B, M, M): every image's pairwise self-IoU in one
+    vmapped kernel launch (one compile per (B, M))."""
+    return jax.vmap(lambda b: iou_matrix_pallas(
+        b, b, interpret=interpret))(boxes)

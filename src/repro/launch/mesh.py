@@ -34,7 +34,8 @@ def make_host_mesh(model: int = 1, data: int = 1):
     n = len(jax.devices())
     model = min(model, n)
     data = max(1, min(data, n // model))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh) -> tuple:

@@ -30,15 +30,33 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass, field
+from typing import List
 
 import numpy as np
 
 
-def run_federation(args) -> int:
+@dataclass
+class FederationRun:
+    """What one ``--federation`` run served: the env and policy it built,
+    the request list, one result per request, and the measured window on
+    the ``time.perf_counter`` clock (warm-up excluded)."""
+    env: object
+    agent: object
+    requests: List[int]
+    results: list
+    t0: float
+    t1: float
+    stats: dict = field(default_factory=dict)
+    shard_metrics: dict = field(default_factory=dict)
+
+
+def run_federation(args) -> FederationRun:
     from repro.core.sac import SAC, SACConfig
     from repro.federation.env import ArmolEnv
     from repro.federation.providers import default_providers
     from repro.federation.traces import generate_traces
+    from repro.obs.metrics import merge_snapshots
     from repro.serving.async_service import AsyncFederationService
     from repro.serving.federation_service import FederationService
 
@@ -116,10 +134,12 @@ def run_federation(args) -> int:
                 obs.open_serving_log(
                     [p.name for p in env.traces.providers],
                     env.traces.gts if env.mode == "gt" else None)
-            t0 = time.time()
+            t0 = time.perf_counter()
             futures = [svc.submit(i) for i in reqs]
             results = [f.result() for f in futures]
-            dt = time.time() - t0
+            t1 = time.perf_counter()
+            stats = dict(svc.stats)
+            shard_metrics = merge_snapshots(*svc.extra_metric_snapshots())
             extra = (f" mean_flush={svc.mean_flush_size():.1f}"
                      f" flushes={svc.stats['flushes']}"
                      f" shards={svc.workers}")
@@ -127,7 +147,7 @@ def run_federation(args) -> int:
                 extra += (f" segments="
                           f"{pool.schedule.segment_index(svc.clock) + 1}")
             if obs is not None:
-                obs.write_metrics(svc.extra_metric_snapshots())
+                obs.write_metrics([shard_metrics])
     else:
         svc = FederationService(env, agent, obs=obs)
         svc.handle(reqs[0])                             # warm jit
@@ -135,15 +155,17 @@ def run_federation(args) -> int:
             obs.open_serving_log(
                 [p.name for p in env.traces.providers],
                 env.traces.gts if env.mode == "gt" else None)
-        t0 = time.time()
+        t0 = time.perf_counter()
         results = [svc.handle(i) for i in reqs]
-        dt = time.time() - t0
+        t1 = time.perf_counter()
+        stats, shard_metrics = {}, {}
         extra = ""
         if obs is not None:
             obs.write_metrics()
 
     cost = sum(r.cost_milli_usd for r in results)
     lat = np.asarray([r.latency_ms for r in results])
+    dt = t1 - t0
     print(f"[serve] {len(results)} requests in {dt:.2f}s "
           f"({len(results) / max(dt, 1e-9):.0f} req/s){extra}")
     print(f"[serve] accounted cost={cost:.1f} mUSD, modeled latency "
@@ -154,10 +176,11 @@ def run_federation(args) -> int:
         print(f"[serve] observability artifacts in {args.obs_dir} "
               f"(render: python -m repro.launch.obs_report "
               f"{args.obs_dir})")
-    return 0
+    return FederationRun(env, agent, reqs, results, t0, t1, stats,
+                         shard_metrics)
 
 
-def main():
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="",
                     help="LM architecture (required unless --federation)")
@@ -225,17 +248,28 @@ def main():
     ap.add_argument("--trace-sample", type=float, default=0.0,
                     help="fraction of requests traced through the async "
                          "plane (0 = tracing off/free; needs --obs-dir)")
-    args = ap.parse_args()
+    return ap
 
+
+def parse_args(argv=None) -> argparse.Namespace:
+    args = _parser().parse_args(argv)
     if args.requests is None:
         args.requests = 400 if args.federation else 8
     if args.scenario and not args.use_async:
         # mid-stream pool swaps live in the async service's flush path
         args.use_async = True
+    return args
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.federation:
-        return run_federation(args)
+        run_federation(args)
+        return 0
     if not args.arch:
-        ap.error("--arch is required unless --federation is given")
+        _parser().error("--arch is required unless --federation is given")
 
     from repro.configs.base import get_arch
     from repro.serving.engine import Request, ServeEngine

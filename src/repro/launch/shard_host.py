@@ -14,6 +14,9 @@ Every host must be started with the SAME roster arguments
 connect-time ``hello`` handshake refuses hosts whose trace fingerprints
 or config differ, because such hosts would answer valid-but-different
 rows and silently break cross-shard bit-parity.  See ``docs/serving.md``.
+
+A host is host-only: it pins jax to the CPU and uses the numpy IoU twin,
+so it never reaches for a chip that the serving front holds.
 """
 from __future__ import annotations
 
@@ -34,19 +37,17 @@ def main(argv=None) -> int:
     ap.add_argument("--voting", default="affirmative")
     ap.add_argument("--ablation", default="wbf")
     ap.add_argument("--iou-thr", type=float, default=0.5)
-    ap.add_argument("--use-kernel", default="auto",
-                    choices=["auto", "true", "false"])
     args = ap.parse_args(argv)
 
-    from repro.ensemble.pipeline import resolve_use_kernel
     from repro.federation.providers import default_providers
     from repro.federation.traces import generate_traces
+    from repro.launch.compile_cache import configure_compile_cache
+    from repro.serving.mp_shards import pin_host_only, shard_cfg
     from repro.serving.socket_shards import serve_host
 
-    uk = {"auto": "auto", "true": True, "false": False}[args.use_kernel]
-    cfg = {"voting": args.voting, "ablation": args.ablation,
-           "iou_thr": args.iou_thr,
-           "use_kernel": resolve_use_kernel(uk)}
+    pin_host_only()
+    configure_compile_cache()
+    cfg = shard_cfg(args.voting, args.ablation, args.iou_thr)
     traces = generate_traces(default_providers(), args.images,
                              seed=args.seed)
 

@@ -177,6 +177,8 @@ def main():
                          "results are bit-identical with or without it")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.federation:
         # the shared --steps flag means env steps per epoch here; the LM
         # default of 50 would end training before the first update block

@@ -37,7 +37,7 @@ import multiprocessing as mp
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -69,6 +69,23 @@ def trace_content_digest(traces: TraceSet) -> str:
                                           np.float64).tobytes())
             h.update(np.ascontiguousarray(det.labels, np.int64).tobytes())
     return h.hexdigest()
+
+
+def shard_cfg(voting: str = "affirmative", ablation: str = "wbf",
+              iou_thr: float = 0.5) -> Dict[str, object]:
+    """The ensemble config every shard child (pipe worker or TCP host)
+    serves with.  Children are host-only and use the numpy IoU twin: the
+    chip belongs to the serving parent, and a second process that
+    reaches for it fails or hangs."""
+    return {"voting": voting, "ablation": ablation, "iou_thr": iou_thr,
+            "use_kernel": False}
+
+
+def pin_host_only() -> None:
+    """Pin a shard child to the CPU platform before any jax call, so an
+    accidental jax use there can never initialize the chip's backend."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
 
 class ShardOpHandler:
@@ -240,6 +257,10 @@ class ShardOpHandler:
                 agg_sizes[k] = agg_sizes.get(k, 0) + v
         with self._wall_lock:
             wall = {k: round(v, 6) for k, v in sorted(self.wall.items())}
+        # summed across shards by merge_snapshots: the number of shard
+        # children holding a jax backend, which must stay 0
+        self.wreg.gauge("worker.jax_backends_initialized").set(
+            _jax_backends_initialized())
         return {"cache_sizes": agg_sizes,
                 "cache_sizes_by_core": by_core,
                 "stats": agg_stats,
@@ -250,6 +271,16 @@ class ShardOpHandler:
                 "cached_images": self.cores[key].cached_images(),
                 "n_cores": len(self.cores),
                 "pid": os.getpid()}
+
+
+def _jax_backends_initialized() -> bool:
+    """Whether this process has initialized any jax backend (a shard
+    child must not: see :func:`pin_host_only`)."""
+    import sys
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
 
 
 def _worker_main(conn, traces: TraceSet,
@@ -263,6 +294,7 @@ def _worker_main(conn, traces: TraceSet,
     desynced reply is detected, never mis-attributed).  An unreadable
     pipe means the parent is gone and the worker exits.
     """
+    pin_host_only()
     handler = ShardOpHandler(traces, cfg)
     conn.send((0, "ok", "ready"))
     while True:
@@ -299,24 +331,18 @@ class ProcessShardedSubsetEvaluationCore:
     def __init__(self, traces: TraceSet, *, n_shards: int = 4,
                  voting: str = "affirmative", ablation: str = "wbf",
                  iou_thr: float = 0.5,
-                 use_kernel: Union[bool, str] = "auto",
                  mp_context: str = "spawn",
                  start_timeout_s: float = 180.0,
                  op_timeout_s: float = 300.0):
         if n_shards < 1:
             raise ValueError(f"n_shards must be >= 1, got {n_shards}")
-        from repro.ensemble.pipeline import resolve_use_kernel
         self.n_shards = int(n_shards)
         self.traces = traces
         self.n_providers = traces.n_providers
         self.costs = traces.costs()
         self.full_mask = (1 << self.n_providers) - 1
         self.op_timeout_s = float(op_timeout_s)
-        # resolve "auto" in the parent: every worker must make the same
-        # kernel decision the parent would, regardless of its own env
-        self._cfg = {"voting": voting, "ablation": ablation,
-                     "iou_thr": iou_thr,
-                     "use_kernel": resolve_use_kernel(use_kernel)}
+        self._cfg = shard_cfg(voting, ablation, iou_thr)
         self._ctx = mp.get_context(mp_context)
         self._procs: List[mp.Process] = []
         self._conns = []
@@ -363,9 +389,11 @@ class ProcessShardedSubsetEvaluationCore:
              **kw) -> "ProcessShardedSubsetEvaluationCore":
         """A process-sharded core with the same ensemble configuration as
         ``core`` (fresh, shared-nothing caches — a process shard never
-        migrates another core's memo)."""
+        migrates another core's memo).  The workers use the numpy IoU
+        twin whatever ``core`` uses (:func:`shard_cfg`)."""
         return cls(core.traces, n_shards=n_shards, mp_context=mp_context,
-                   **core.config(), **kw)
+                   voting=core.voting, ablation=core.ablation,
+                   iou_thr=core.iou_thr, **kw)
 
     @classmethod
     def for_pool(cls, pool, n_shards: int, *, mp_context: str = "spawn",
@@ -376,8 +404,7 @@ class ProcessShardedSubsetEvaluationCore:
         bit-identically in-process."""
         return cls(pool.base_traces, n_shards=n_shards,
                    mp_context=mp_context,
-                   voting=pool.voting, ablation=pool.ablation,
-                   use_kernel=pool.use_kernel, **kw)
+                   voting=pool.voting, ablation=pool.ablation, **kw)
 
     def bind_obs(self, metrics=None, tracer=None) -> None:
         """Attach a :class:`~repro.obs.metrics.MetricsRegistry` (and

@@ -53,7 +53,7 @@ import struct
 import threading
 import time
 from bisect import bisect_left
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -63,6 +63,7 @@ from repro.federation.evaluation import (LatticeResult,
                                          action_to_mask)
 from repro.federation.traces import TraceSet
 from repro.serving.mp_shards import (ShardOpHandler, ShardWorkerError,
+                                     pin_host_only, shard_cfg,
                                      trace_content_digest)
 
 _LEN = struct.Struct(">Q")
@@ -156,6 +157,7 @@ def _host_main(report_conn, traces: TraceSet, cfg: Dict[str, object],
     """Spawned shard-host process body: bind, report the bound port back
     over ``report_conn`` (ephemeral ports — the parent learns where to
     connect), then serve until stopped."""
+    pin_host_only()
     srv = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
     srv.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
     srv.bind((host, port))
@@ -209,7 +211,6 @@ class SocketShardedSubsetEvaluationCore:
                  hosts: Optional[Sequence[Tuple[str, int]]] = None,
                  voting: str = "affirmative", ablation: str = "wbf",
                  iou_thr: float = 0.5,
-                 use_kernel: Union[bool, str] = "auto",
                  mp_context: str = "spawn",
                  start_timeout_s: float = 180.0,
                  op_timeout_s: float = 300.0,
@@ -218,7 +219,6 @@ class SocketShardedSubsetEvaluationCore:
                  health_timeout_s: float = 2.0,
                  health_failures_to_condemn: int = 2,
                  virtual_nodes: int = 64):
-        from repro.ensemble.pipeline import resolve_use_kernel
         self.traces = traces
         self.n_providers = traces.n_providers
         self.costs = traces.costs()
@@ -229,11 +229,7 @@ class SocketShardedSubsetEvaluationCore:
         self.health_timeout_s = float(health_timeout_s)
         self.health_failures_to_condemn = int(health_failures_to_condemn)
         self.virtual_nodes = int(virtual_nodes)
-        # resolve "auto" client-side: every host must make the same
-        # kernel decision this client would, regardless of its own env
-        self._cfg = {"voting": voting, "ablation": ablation,
-                     "iou_thr": iou_thr,
-                     "use_kernel": resolve_use_kernel(use_kernel)}
+        self._cfg = shard_cfg(voting, ablation, iou_thr)
         self._closed = False
         self._procs: List[Optional[mp.process.BaseProcess]] = []
         self._addrs: List[Tuple[str, int]] = []
@@ -287,8 +283,10 @@ class SocketShardedSubsetEvaluationCore:
     def like(cls, core: SubsetEvaluationCore, n_shards: int,
              **kw) -> "SocketShardedSubsetEvaluationCore":
         """A socket-sharded core with the same ensemble configuration as
-        ``core`` (fresh, shared-nothing caches on every host)."""
-        return cls(core.traces, n_shards=n_shards, **core.config(), **kw)
+        ``core`` (fresh, shared-nothing caches on every host).  The hosts
+        use the numpy IoU twin whatever ``core`` uses (:func:`shard_cfg`)."""
+        return cls(core.traces, n_shards=n_shards, voting=core.voting,
+                   ablation=core.ablation, iou_thr=core.iou_thr, **kw)
 
     @classmethod
     def for_pool(cls, pool, n_shards: int,
@@ -298,8 +296,7 @@ class SocketShardedSubsetEvaluationCore:
         rebuilt bit-identically host-side (same contract as the process
         plane)."""
         return cls(pool.base_traces, n_shards=n_shards,
-                   voting=pool.voting, ablation=pool.ablation,
-                   use_kernel=pool.use_kernel, **kw)
+                   voting=pool.voting, ablation=pool.ablation, **kw)
 
     # -- startup ---------------------------------------------------------
     def _spawn_local_hosts(self, traces: TraceSet, mp_context: str,

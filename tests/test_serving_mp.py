@@ -67,6 +67,17 @@ def _assert_results_equal(got, ref):
 
 # -- direct core parity ----------------------------------------------------
 
+def test_workers_hold_no_jax_backend(proc_core):
+    """Workers are host-only: they serve with the numpy IoU twin and
+    never initialize a jax backend, so on a chip they cannot reach for
+    the device the serving parent holds."""
+    assert proc_core._cfg["use_kernel"] is False
+    for img in range(6):
+        proc_core.ensemble(img, (1 << N) - 1)
+    gauges = proc_core.metrics_snapshot()["gauges"]
+    assert gauges["worker.jax_backends_initialized"] == 0
+
+
 def test_core_matches_unsharded_bit_for_bit(proc_core):
     ref = SubsetEvaluationCore(TR)
     rng = np.random.default_rng(0)
@@ -314,7 +325,7 @@ def test_snapshot_carries_regeneration_seed():
     pool = DynamicProviderPool(providers, schedule, n_images=12, seed=7)
     core = ProcessShardedSubsetEvaluationCore(
         pool.base_traces, n_shards=2, voting=pool.voting,
-        ablation=pool.ablation, use_kernel=pool.use_kernel)
+        ablation=pool.ablation)
     try:
         drifted = next(s for s in range(100) if pool.view_at(s).dets_key
                        != pool.view_at(0).dets_key)

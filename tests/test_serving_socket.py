@@ -88,6 +88,52 @@ def test_socket_core_matches_unsharded_bit_for_bit(sock_core):
     np.testing.assert_allclose(la.ap, lb.ap)
 
 
+def test_hosts_hold_no_jax_backend(sock_core):
+    """Shard hosts are host-only, like the process plane's workers."""
+    assert sock_core._cfg["use_kernel"] is False
+    for img in range(6):
+        sock_core.ensemble(img, (1 << N) - 1)
+    gauges = sock_core.metrics_snapshot()["gauges"]
+    assert gauges["worker.jax_backends_initialized"] == 0
+
+
+def test_hand_started_host_passes_hello_and_serves_twin_rows():
+    """A host started by hand (``python -m repro.launch.shard_host``) with
+    the front's roster arguments passes the hello handshake and answers
+    the numpy twin's rows."""
+    import re
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ,
+               PYTHONPATH=os.path.join(root, "src") + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "repro.launch.shard_host", "--port", "0",
+         "--images", str(len(TR)), "--seed", "7"],
+        stdout=subprocess.PIPE, text=True, env=env)
+    core = None
+    try:
+        line = proc.stdout.readline()
+        port = int(re.search(r" on [^ :]+:(\d+) ", line).group(1))
+        core = SocketShardedSubsetEvaluationCore(
+            TR, hosts=[("127.0.0.1", port)])
+        ref = SubsetEvaluationCore(TR, use_kernel=False)
+        for img in range(5):
+            a, b = core.ensemble(img, 5), ref.ensemble(img, 5)
+            np.testing.assert_array_equal(a.boxes, b.boxes)
+            np.testing.assert_array_equal(a.scores, b.scores)
+        gauges = core.metrics_snapshot()["gauges"]
+        assert gauges["worker.jax_backends_initialized"] == 0
+    finally:
+        if core is not None:
+            core.close()
+        proc.kill()
+        proc.wait(timeout=10)
+        proc.stdout.close()
+    assert proc.poll() is not None
+
+
 def test_ring_routing_is_total_and_consistent(sock_core):
     groups = sock_core.partition(range(len(TR)))
     assert sorted(i for g in groups.values() for i in g) == \
