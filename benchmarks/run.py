@@ -1432,105 +1432,6 @@ def bench_frontier():
     return out
 
 
-# ---------------------------------------------------------------------------
-
-def bench_obs_overhead():
-    """Observability overhead on the async serving hot path.
-
-    Two identically configured ``AsyncFederationService`` instances over
-    the same env — one bare, one with the full ``repro.obs`` stack
-    attached (metrics registry + JSONL serving log + sampled tracing) —
-    each drain the same warm request stream; the runs interleave
-    round-by-round (``_best_of``), so machine-speed and load spikes
-    cancel in the ratio.  The gated ``throughput_ratio`` =
-    instrumented/bare requests-per-second must stay ~1.0: the design
-    contract is that observability on the hot path is within noise.
-    Result parity between the two services is asserted outright.
-    """
-    import tempfile
-
-    from repro.core.sac import SAC, SACConfig
-    from repro.federation.env import ArmolEnv
-    from repro.federation.providers import default_providers
-    from repro.federation.traces import generate_traces
-    from repro.obs import Obs
-    from repro.serving.async_service import AsyncFederationService
-
-    n_images = min(IMAGES, 120)
-    n_reqs = int(os.environ.get("REPRO_BENCH_REQUESTS", "480"))
-    max_batch = int(os.environ.get("REPRO_BENCH_MAX_BATCH", "16"))
-    workers = int(os.environ.get("REPRO_BENCH_WORKERS", "2"))
-    rounds = int(os.environ.get("REPRO_BENCH_ROUNDS", "5"))
-    trace_sample = float(os.environ.get("REPRO_BENCH_TRACE_SAMPLE",
-                                        "0.01"))
-
-    traces = generate_traces(default_providers(), n_images, seed=0)
-    env = ArmolEnv(traces, mode="gt", beta=0.0, seed=1)
-    agent = SAC(SACConfig(state_dim=env.state_dim,
-                          n_providers=env.n_providers, hidden=(32, 32)))
-    rng = np.random.default_rng(0)
-    reqs = [int(i) for i in rng.integers(0, n_images, n_reqs)]
-    env.core.precompute(np.arange(n_images))
-
-    obs_dir = tempfile.mkdtemp(prefix="obs-bench-")
-    obs = Obs(obs_dir, trace_sample=trace_sample)
-    obs.open_serving_log([p.name for p in traces.providers], traces.gts)
-    svc_bare = AsyncFederationService(env, agent, max_batch=max_batch,
-                                      workers=workers)
-    svc_inst = AsyncFederationService(env, agent, max_batch=max_batch,
-                                      workers=workers, obs=obs)
-    try:
-        # precompute the full subset lattice on the instrumented
-        # service's shard cores so the serving log's AP50 column is a
-        # table hit (the documented deployment shape for gt-scored
-        # logging) — the stochastic policy samples fresh masks every
-        # round, and without the lattice each unseen (image, mask) pair
-        # would pay a fresh AP matching inside the timed region
-        for i in range(n_images):
-            svc_inst.core.evaluate_lattice(i)
-        # warm both planes (jit flush shapes + shard memos) and assert
-        # the instrumented service is result-identical to the bare one
-        ref = svc_bare.handle_many(reqs[:64])
-        got = svc_inst.handle_many(reqs[:64])
-        assert all(
-            a.cost_milli_usd == b.cost_milli_usd
-            and a.latency_ms == b.latency_ms
-            and np.array_equal(a.detections.boxes, b.detections.boxes)
-            for a, b in zip(ref, got)), "obs on/off results diverged"
-
-        def _drain(svc):
-            futures = [svc.submit(i) for i in reqs]
-            for f in futures:
-                f.result()
-
-        t_bare, t_inst = _best_of(lambda: _drain(svc_bare),
-                                  lambda: _drain(svc_inst),
-                                  rounds=rounds)
-    finally:
-        svc_bare.close()
-        svc_inst.close()
-        obs.write_metrics(svc_inst.extra_metric_snapshots())
-        obs.close()
-
-    out = {
-        "n_requests": n_reqs, "n_images": n_images,
-        "max_batch": max_batch, "workers": workers,
-        "trace_sample": trace_sample, "rounds": rounds,
-        "bare_rps": round(n_reqs / t_bare, 1),
-        "instrumented_rps": round(n_reqs / t_inst, 1),
-        # >= 1.0 means instrumented matched/beat bare that run; the gate
-        # (tools/check_bench.py) fails if the committed ratio regresses
-        "throughput_ratio": round(t_bare / t_inst, 4),
-    }
-    _save("obs_overhead", out)
-    _emit("obs_overhead/bare", t_bare * 1e6 / n_reqs,
-          f"rps={out['bare_rps']}")
-    _emit("obs_overhead/instrumented", t_inst * 1e6 / n_reqs,
-          f"rps={out['instrumented_rps']};"
-          f"ratio={out['throughput_ratio']}")
-    return out
-
-
 BENCHES = {
     "provider_ap": bench_provider_ap,
     "ensemble_combos": bench_ensemble_combos,
@@ -1547,7 +1448,6 @@ BENCHES = {
     "roofline": bench_roofline,
     "kernels": bench_kernels,
     "frontier": bench_frontier,
-    "obs_overhead": bench_obs_overhead,
 }
 
 

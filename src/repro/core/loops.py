@@ -221,13 +221,12 @@ def run_off_policy(agent, env: ArmolEnv, *, lanes: int = 1, epochs: int = 5,
     """
     if lanes < 1:
         raise ValueError(f"lanes must be >= 1, got {lanes}")
-    # observability (repro.obs.Obs): tick latency, update_block
-    # throughput and replay occupancy — reads clocks and copies values
+    # observability (repro.obs.Obs): tick latency, update iterations
+    # and replay occupancy — reads clocks and copies values
     # only, so training results are bit-identical with obs on or off
     _obs_on = obs is not None and obs.enabled
     if _obs_on:
         _h_tick = obs.metrics.histogram("train.tick_ms")
-        _h_blk = obs.metrics.histogram("train.update_block_ms")
         _g_occ = obs.metrics.gauge("train.replay_occupancy")
         _c_upd = obs.metrics.counter("train.update_iters")
     rng = np.random.default_rng(seed)
@@ -285,7 +284,6 @@ def run_off_policy(agent, env: ArmolEnv, *, lanes: int = 1, epochs: int = 5,
                         f"update is scheduled at step {k * update_every} "
                         "but no transitions have been stored "
                         f"(update_after={update_after})")
-                _blk_t0 = time.monotonic() if _obs_on else 0.0
                 if update_block is not None:
                     blk = buf.sample_block(update_iters, batch_size)
                     if device_buf:
@@ -300,7 +298,6 @@ def run_off_policy(agent, env: ArmolEnv, *, lanes: int = 1, epochs: int = 5,
                         agent.update(buf.sample(batch_size))
                 if _obs_on:
                     _c_upd.inc(update_iters)
-                    _h_blk.observe((time.monotonic() - _blk_t0) * 1e3)
             if _obs_on:
                 _g_occ.set(len(buf))
                 _h_tick.observe((time.monotonic() - _tick_t0) * 1e3)

@@ -18,12 +18,19 @@ DIR`` renders the directory back into per-regime summaries.  The design
 contract, enforced by ``tests/test_obs_parity.py``: serving and training
 RESULTS are bit-identical with observability on or off — obs reads
 timing and copies values, it never touches an rng, a cache key, or an
-accounting quantity — and the instrumented hot path stays within noise
-of the bare one (``benchmarks/run.py obs_overhead``, gated).
+accounting quantity.
+
+An enabled ``Obs`` also times the served path's phases
+(:meth:`Obs.phase`) and every garbage collection
+(:class:`~repro.obs.tracing.GcPauses`) into registry histograms and, once
+jax is imported, as ``jax.profiler.TraceAnnotation`` events: under
+``jax.profiler.trace`` each phase and collection sits on the device
+trace's clock.
 
 A disabled ``Obs`` (or simply passing ``obs=None`` everywhere) costs a
-branch check per call site: the registry hands out no-op metrics and the
-tracer never samples.
+branch check per call site: the registry hands out no-op metrics, the
+tracer never samples, no annotation is entered and no gc callback is
+registered.
 """
 from __future__ import annotations
 
@@ -38,7 +45,8 @@ from repro.obs.metrics import (DEFAULT_MS_BUCKETS, MetricsRegistry,
                                hist_quantile, merge_snapshots)
 from repro.obs.prom import parse_prometheus, render_prometheus
 from repro.obs.serving_log import ServingLog, read_serving_log
-from repro.obs.tracing import NULL_SPAN, Tracer
+from repro.obs.tracing import (NULL_SPAN, GcPauses, Span, Tracer,
+                               profiler_annotation)
 
 __all__ = ["Obs", "MetricsRegistry", "Tracer", "ServingLog",
            "merge_snapshots", "counters_snapshot", "empty_snapshot",
@@ -76,6 +84,20 @@ class Obs:
             seed=seed)
         self.serving_log: Optional[ServingLog] = None
         self.events: List[dict] = []
+        self._annotate = profiler_annotation() if self.enabled else None
+        self._gc = GcPauses(self.metrics, self._annotate) \
+            if self.enabled else None
+
+    # -- phases ------------------------------------------------------------
+    def phase(self, name: str, hist=None, trace_id: Optional[str] = None,
+              parent: Optional[str] = None, cpu_hist=None,
+              **attrs) -> Span:
+        """Context manager timing one phase of the served path: a profiler
+        annotation ``name``, its wall time in ``hist`` (thread CPU time in
+        ``cpu_hist``), and a JSONL span under ``parent`` when ``trace_id``
+        is a sampled trace.  Call only on an enabled ``Obs``."""
+        return Span(self.tracer, name, trace_id, parent, attrs,
+                    annotate=self._annotate, hist=hist, cpu_hist=cpu_hist)
 
     # -- serving log -------------------------------------------------------
     def open_serving_log(self, provider_names: Optional[Sequence[str]]
@@ -133,6 +155,8 @@ class Obs:
 
     # -- lifecycle ---------------------------------------------------------
     def close(self) -> None:
+        if self._gc is not None:
+            self._gc.close()
         if self.serving_log is not None:
             self.serving_log.close()
         with self._lock:

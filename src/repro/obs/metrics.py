@@ -209,7 +209,9 @@ class MetricsRegistry:
         with self._lock:
             h = self._histograms.get(name)
             if h is None:
-                h = self._histograms[name] = Histogram(threading.Lock(),
+                # re-entrant: a gc callback (obs.tracing.GcPauses) can
+                # observe while its own thread holds the lock mid-snapshot
+                h = self._histograms[name] = Histogram(threading.RLock(),
                                                        bounds)
             elif h.bounds != tuple(float(b) for b in bounds):
                 raise ValueError(

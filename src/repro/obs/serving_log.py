@@ -32,8 +32,7 @@ tuple of references to a queue (the inputs are immutable — result
 objects, int masks, fee vectors that are never mutated in place) and a
 dedicated daemon thread does all JSON formatting, AP scoring fallback
 and file I/O.  The serving threads' critical path pays a list build and
-one lock/notify per flush; the ``obs_overhead`` benchmark gates that
-this stays within noise of logging off.  Consequences:
+one lock/notify per flush.  Consequences:
 
 * ``tail()`` / ``n_records`` are eventually consistent — call
   :meth:`flush` (a write barrier) before reading them in tests.

@@ -21,12 +21,23 @@ checks on the hot path, nothing else.  The sampler uses its own
 ``random.Random(seed)``: it never touches numpy global state or any
 env/agent rng, which is what keeps traced and untraced runs
 bit-identical.
+
+A :class:`Span` is also the one phase timer of the served path: given an
+annotation type (``jax.profiler.TraceAnnotation``, see
+:func:`profiler_annotation`) it enters the phase on the profiler's trace,
+on the same clock as the device's events, and given a histogram it
+observes the phase's wall time (and, with ``cpu_hist``, the thread's CPU
+time) — for every request, sampled or not.  :class:`GcPauses` puts every
+garbage collection on the same two sinks.
 """
 from __future__ import annotations
 
+import gc
 import random
+import sys
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -53,37 +64,125 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class Span:
-    __slots__ = ("_tracer", "name", "trace_id", "span_id", "parent_id",
-                 "attrs", "_t0", "_ts")
+def no_phase(*args, **kwargs) -> _NullSpan:
+    """The phase factory of a service without observability: every phase
+    is the shared no-op span (no clock, no annotation, no histogram)."""
+    return NULL_SPAN
 
-    def __init__(self, tracer: "Tracer", name: str, trace_id: str,
-                 parent_id: Optional[str], attrs: Dict):
-        self._tracer = tracer
+
+def profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` when jax is already imported, else
+    ``None``: observability never imports jax itself, so host-only
+    processes (shard workers, socket hosts) stay jax-free."""
+    if "jax" not in sys.modules:
+        return None
+    import jax.profiler
+    return jax.profiler.TraceAnnotation
+
+
+class Span:
+    """One timed interval, on up to three sinks: a JSONL span when it
+    belongs to a sampled trace (``tracer`` and ``trace_id`` given), a
+    profiler annotation when given the annotation type, and the wall time
+    in ``hist`` (the thread's CPU time in ``cpu_hist``) when given them.
+    Durations come from ``time.perf_counter``; ``ts`` is read once, at
+    entry, and only for a recorded span."""
+
+    __slots__ = ("_tracer", "name", "trace_id", "span_id", "parent_id",
+                 "attrs", "_t0", "_ts", "_cpu0", "_ann", "_hist",
+                 "_cpu_hist")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 trace_id: Optional[str], parent_id: Optional[str],
+                 attrs: Dict, annotate=None, hist=None, cpu_hist=None):
+        self._tracer = tracer if trace_id is not None else None
         self.name = name
-        self.trace_id = trace_id
-        self.span_id = tracer._next_span_id()
+        self.trace_id = trace_id if self._tracer is not None else None
+        self.span_id = (self._tracer._next_span_id()
+                        if self._tracer is not None else None)
         self.parent_id = parent_id
         self.attrs = attrs
+        self._ann = annotate(name) if annotate is not None else None
+        self._hist = hist
+        self._cpu_hist = cpu_hist
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "Span":
-        self._ts = time.time()
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._ts = time.time()
         self._t0 = time.perf_counter()
+        if self._cpu_hist is not None:      # CPU interval inside the wall
+            self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, exc_type, *exc) -> None:
-        if exc_type is not None:
-            self.attrs["error"] = exc_type.__name__
-        self._tracer.record({
-            "name": self.name, "trace": self.trace_id,
-            "span": self.span_id, "parent": self.parent_id,
-            "ts": self._ts,
-            "dur_ms": (time.perf_counter() - self._t0) * 1e3,
-            "attrs": self.attrs})
+        if self._cpu_hist is not None:
+            self._cpu_hist.observe((time.thread_time() - self._cpu0) * 1e3)
+        dur_ms = (time.perf_counter() - self._t0) * 1e3
+        if self._hist is not None:
+            self._hist.observe(dur_ms)
+        if self._tracer is not None:
+            if exc_type is not None:
+                self.attrs["error"] = exc_type.__name__
+            self._tracer.record({
+                "name": self.name, "trace": self.trace_id,
+                "span": self.span_id, "parent": self.parent_id,
+                "ts": self._ts, "dur_ms": dur_ms, "attrs": self.attrs})
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, *exc)
         return None
+
+
+class GcPauses:
+    """``gc.callbacks`` hook: each collection is a ``gc.gen<k>`` profiler
+    annotation (entered on ``"start"``, left on ``"stop"``, on whichever
+    thread collects) and one ``runtime.gc_ms.gen<k>`` observation, ms.
+
+    The hook holds its registry by weak reference (only the histograms,
+    made up front, strongly) and leaves ``gc.callbacks`` once the registry
+    is gone, or on :meth:`close`.  Collections never overlap (the
+    interpreter runs one at a time), so one pending start is all the state
+    there is.  A collection can start while its own thread holds a
+    histogram's lock, so the callback takes no lock but those histograms'
+    own, which are re-entrant (``MetricsRegistry.histogram``)."""
+
+    def __init__(self, metrics, annotate=None):
+        self._hists = tuple(metrics.histogram(f"runtime.gc_ms.gen{g}")
+                            for g in range(len(gc.get_threshold())))
+        self._annotate = annotate
+        self._ann = None
+        self._t0 = 0.0
+        gc.callbacks.append(self)
+        self._detach = weakref.finalize(metrics, _drop_gc_hook, self)
+        self._detach.atexit = False
+
+    def __call__(self, phase: str, info: dict) -> None:
+        gen = info["generation"]
+        if phase == "start":
+            if self._annotate is not None:
+                self._ann = self._annotate(f"gc.gen{gen}")
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        ms = (time.perf_counter() - self._t0) * 1e3
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self._hists[gen].observe(ms)
+
+    def close(self) -> None:
+        self._detach()
+
+
+def _drop_gc_hook(hook: GcPauses) -> None:
+    try:
+        gc.callbacks.remove(hook)
+    except ValueError:
+        pass
 
 
 class Tracer:
@@ -94,7 +193,7 @@ class Tracer:
     sample:    fraction of requests that get a trace (0 disables).
     writer:    optional callback invoked with each finished span dict
                (the ``Obs`` umbrella wires a JSONL appender here).
-    max_spans: in-memory ring capacity for :meth:`drain`/reporting.
+    max_spans: in-memory ring capacity for :meth:`spans`.
     seed:      sampler seed — deterministic, isolated from user rngs.
     """
 
@@ -151,12 +250,6 @@ class Tracer:
             self._writer(rec)
 
     # -- reporting --------------------------------------------------------
-    def drain(self) -> List[dict]:
-        with self._lock:
-            out = list(self._spans)
-            self._spans.clear()
-        return out
-
     def spans(self) -> List[dict]:
         with self._lock:
             return list(self._spans)

@@ -47,7 +47,7 @@ import numpy as np
 
 from repro.federation.env import ArmolEnv
 from repro.obs.metrics import MetricsRegistry, merge_snapshots
-from repro.obs.tracing import NULL_SPAN
+from repro.obs.tracing import NULL_SPAN, no_phase
 from repro.serving.federation_service import (FederationResult,
                                               FederationService)
 from repro.serving.transports import ShardTransport, get_transport
@@ -175,12 +175,31 @@ class AsyncFederationService:
                           if k == "max_flush"
                           else self._metrics.counter("serving." + k))
                       for k in _STAT_KEYS}
+        # phases of the served path (obs.phase: profiler annotation +
+        # histogram + span of a sampled request); with obs off every
+        # phase is the shared no-op span and no clock is read
+        self._phase = obs.phase if self._obs_on else no_phase
+        self._h_policy = self._h_shard_wait = self._h_iou_table = \
+            self._h_assemble_cpu = None
         if self._obs_on:
             self._h_flush_size = self._metrics.histogram(
                 "serving.flush_size",
                 bounds=tuple(float(b) for b in range(1, 65)))
+            # enqueue -> popped by the dispatcher, per request
             self._h_queue_wait = self._metrics.histogram(
                 "serving.queue_wait_ms")
+            # the flush's decision: feature gather, padding, forward,
+            # actions on the host
+            self._h_policy = self._metrics.histogram("serving.policy_ms")
+            # handed to a shard thread -> that thread starts on it
+            self._h_shard_wait = self._metrics.histogram(
+                "serving.shard_wait_ms")
+            # thread plane: one shard part's IoU tables, and the thread
+            # CPU time of its whole assembly
+            self._h_iou_table = self._metrics.histogram(
+                "serving.iou_table_ms")
+            self._h_assemble_cpu = self._metrics.histogram(
+                "serving.assemble_cpu_ms")
         # per-shard RPC latency histograms + condemned-shard counters
         # always land in the service's registry; worker-shipped spans
         # only when tracing is on (no-op for inline transports)
@@ -280,18 +299,65 @@ class AsyncFederationService:
                 batch = [self._queue.popleft()
                          for _ in range(min(self.max_batch,
                                             len(self._queue)))]
+                t_pop = time.monotonic() if self._obs_on else 0.0
                 clock = self._scn_clock
                 if self.pool is not None:
                     self._scn_clock += len(batch)
             try:
-                self._flush(batch, clock, reason)
+                self._flush(batch, clock, reason, t_pop)
             except BaseException as e:   # keep serving after a bad flush
                 for _, _, fut, _ in batch:
                     if not fut.done():
                         fut.set_exception(e)
 
-    def _flush(self, batch, clock: int, reason: str = "flush_full") -> None:
-        t0 = time.monotonic() if self._tracer is not None else 0.0
+    def _flush(self, batch, clock: int, reason: str = "flush_full",
+               t_pop: float = 0.0) -> None:
+        span = NULL_SPAN
+        if self._obs_on:
+            self._h_flush_size.observe(len(batch))
+            self._h_queue_wait.observe_batch(
+                [(t_pop - b[1]) * 1e3 for b in batch])
+            # the flush span hangs off the first sampled request of the
+            # batch; the per-shard assembly hangs off it as child spans
+            tids = [b[3] for b in batch if b[3] is not None]
+            tid = tids[0] if tids else None
+            span = self._phase("flush", None, tid, tid, reason=reason,
+                               size=len(batch), clock=int(clock),
+                               n_traced=len(tids))
+        with span:
+            self._flush_batch(batch, clock, reason, span)
+
+    def _decide(self, imgs: np.ndarray, clock: int) -> np.ndarray:
+        """The flush's actions, one row per request, on the host."""
+        sel = getattr(self.agent, "select_for_images", None)
+        if sel is not None:
+            # selector policy: decide straight from the image indices —
+            # no feature forward, no padding; the same call the sync
+            # service makes, so both paths are bit-identical by
+            # construction.  The flush clock pins the pool segment.
+            if self.pool is not None:
+                return np.asarray(sel(imgs, step=clock), np.float32)
+            return np.asarray(sel(imgs), np.float32)
+        if len(imgs) == 1:
+            # same single-state act path as FederationService.handle, so
+            # max_batch=1 is result-identical to the synchronous service
+            a, _ = self.agent.select_action(
+                self.env.features[imgs[0]],
+                deterministic=self._svc.deterministic)
+            return np.asarray(a, np.float32).reshape(1, -1)
+        # pad the flush to max_batch so the batched forward is shape-
+        # stable: one jit compile for the service's lifetime instead of
+        # one per distinct queue depth (row-independent MLP heads make
+        # the padding rows inert)
+        feats = self.env.features[imgs]
+        if len(imgs) < self.max_batch:
+            pad = np.broadcast_to(
+                feats[-1], (self.max_batch - len(imgs), feats.shape[1]))
+            feats = np.concatenate([feats, pad], axis=0)
+        return np.asarray(self._policy.select_batch(feats),
+                          np.float32)[:len(imgs)]
+
+    def _flush_batch(self, batch, clock: int, reason: str, span) -> None:
         imgs = np.asarray([b[0] for b in batch], np.int64)
         costs = lats = None
         snapshot = None
@@ -309,36 +375,8 @@ class AsyncFederationService:
             else:
                 core = self.transport.core_at(clock)
                 self.core = core
-        sel = getattr(self.agent, "select_for_images", None)
-        if sel is not None:
-            # selector policy: decide straight from the image indices —
-            # no feature forward, no padding; the same call the sync
-            # service makes, so both paths are bit-identical by
-            # construction.  The flush clock pins the pool segment.
-            if self.pool is not None:
-                actions = np.asarray(sel(imgs, step=clock), np.float32)
-            else:
-                actions = np.asarray(sel(imgs), np.float32)
-        elif len(batch) == 1:
-            # same single-state act path as FederationService.handle, so
-            # max_batch=1 is result-identical to the synchronous service
-            a, _ = self.agent.select_action(
-                self.env.features[imgs[0]],
-                deterministic=self._svc.deterministic)
-            actions = np.asarray(a, np.float32).reshape(1, -1)
-        else:
-            # pad the flush to max_batch so the batched forward is shape-
-            # stable: one jit compile for the service's lifetime instead
-            # of one per distinct queue depth (row-independent MLP heads
-            # make the padding rows inert)
-            feats = self.env.features[imgs]
-            if len(batch) < self.max_batch:
-                pad = np.broadcast_to(
-                    feats[-1], (self.max_batch - len(batch),
-                                feats.shape[1]))
-                feats = np.concatenate([feats, pad], axis=0)
-            actions = np.asarray(self._policy.select_batch(feats),
-                                 np.float32)[:len(batch)]
+        with self._phase("policy", self._h_policy):
+            actions = self._decide(imgs, clock)
         with self._cv:      # counters race with reset_stats() otherwise
             self._stat["flushes"].inc()
             self._stat[reason].inc()
@@ -346,31 +384,10 @@ class AsyncFederationService:
             if len(batch) > 1:
                 self._stat["batched_requests"].inc(len(batch))
             self._stat["max_flush"].set_max(len(batch))
-        if self._obs_on:
-            now = time.monotonic()
-            self._h_flush_size.observe(len(batch))
-            self._h_queue_wait.observe_batch(
-                [(now - b[1]) * 1e3 for b in batch])
-        # span + log context for the fan-out: the flush span hangs off
-        # the first sampled request of the batch (reason, size, clock);
-        # the serving log gets the flush's segment/reason.  Both are
-        # None-cheap when obs is off.
-        trace_ctx = None
-        if self._tracer is not None:
-            tids = [b[3] for b in batch if b[3] is not None]
-            if tids:
-                # the flush span covers the agent decision + routing; the
-                # per-shard RPC/assembly hangs off it as child spans
-                dur_ms = (time.monotonic() - t0) * 1e3
-                span_id = self._tracer._next_span_id()
-                self._tracer.record({
-                    "name": "flush", "trace": tids[0], "span": span_id,
-                    "parent": tids[0], "ts": time.time() - dur_ms / 1e3,
-                    "dur_ms": dur_ms,
-                    "attrs": {"reason": reason, "size": len(batch),
-                              "clock": int(clock),
-                              "n_traced": len(tids)}})
-                trace_ctx = (tids[0], span_id)
+        # span + log context for the fan-out; both None-cheap when obs
+        # is off
+        trace_ctx = None if span.trace_id is None else \
+            (span.trace_id, span.span_id)
         log_ctx = None
         if self.obs is not None and self.obs.serving_log is not None:
             seg = None if self.pool is None else \
@@ -388,13 +405,15 @@ class AsyncFederationService:
                 self._shard_pools[sid].submit(
                     self._account_shard_mp, sid,
                     [batch[p] for p in positions], positions, snapshot,
-                    acts, n_sel, masks, cost, lat, trace_ctx, log_ctx)
+                    acts, n_sel, masks, cost, lat, trace_ctx, log_ctx,
+                    time.perf_counter() if self._obs_on else 0.0)
         else:
             for sid, positions in self._partition(imgs).items():
                 self._shard_pools[sid].submit(
                     self._account_shard, core, sid,
                     [batch[p] for p in positions], actions[positions],
-                    costs, lats, trace_ctx, log_ctx)
+                    costs, lats, trace_ctx, log_ctx,
+                    time.perf_counter() if self._obs_on else 0.0)
 
     def _partition(self, imgs: np.ndarray):
         groups: dict = {}
@@ -412,20 +431,28 @@ class AsyncFederationService:
         return trace_ctx
 
     def _account_shard(self, core, sid: int, items, actions: np.ndarray,
-                       costs, lats, trace_ctx=None, log_ctx=None) -> None:
+                       costs, lats, trace_ctx=None, log_ctx=None,
+                       t_submit: float = 0.0) -> None:
         """Runs on shard ``sid``'s dedicated thread — the only thread that
         ever touches that shard's dicts (for the flush's captured core)."""
         tid, parent = self._trace_parent(trace_ctx)
         try:
-            with self._tracer.span("shard_assemble", tid, parent=parent,
-                                   shard=sid, n=len(items)) \
-                    if tid is not None else NULL_SPAN:
+            if self._obs_on:
+                self._h_shard_wait.observe(
+                    (time.perf_counter() - t_submit) * 1e3)
+            with self._phase("shard_assemble", None, tid, parent,
+                             cpu_hist=self._h_assemble_cpu, shard=sid,
+                             n=len(items)) as asm:
                 shard = core.shards[sid]
                 imgs = [it[0] for it in items]
-                shard.precompute(imgs)  # one batched IoU launch per shard
-                results = self._svc._account_batch(
-                    imgs, actions, core=shard, costs=costs,
-                    latency_ms=lats, log_ctx=log_ctx)
+                # one batched IoU launch per shard
+                with self._phase("iou_table", self._h_iou_table, tid,
+                                 asm.span_id):
+                    shard.precompute(imgs)
+                with self._phase("fuse", None, tid, asm.span_id):
+                    results = self._svc._account_batch(
+                        imgs, actions, core=shard, costs=costs,
+                        latency_ms=lats, log_ctx=log_ctx)
             for (_, _, fut, _), res in zip(items, results):
                 fut.set_result(res)
         except BaseException as e:
@@ -435,7 +462,8 @@ class AsyncFederationService:
 
     def _account_shard_mp(self, sid: int, items, positions,
                           snapshot, acts, n_sel, masks, cost, lat,
-                          trace_ctx=None, log_ctx=None) -> None:
+                          trace_ctx=None, log_ctx=None,
+                          t_submit: float = 0.0) -> None:
         """RPC twin of ``_account_shard``: runs on shard ``sid``'s
         parent-side thread, which owns that worker/host connection for
         the duration (one batched RPC per flush per shard).  Accounting
@@ -445,6 +473,9 @@ class AsyncFederationService:
         dispatcher keep serving."""
         tid, parent = self._trace_parent(trace_ctx)
         try:
+            if self._obs_on:
+                self._h_shard_wait.observe(
+                    (time.perf_counter() - t_submit) * 1e3)
             span = (self._tracer.span("shard_assemble", tid, parent=parent,
                                       shard=sid, n=len(items))
                     if tid is not None else NULL_SPAN)

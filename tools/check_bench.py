@@ -131,11 +131,6 @@ GATES = {
                  Gate("invariants.rl_dominates_all_providers"),
                  Gate("invariants.hybrid_ge_cascade"),
                  Gate("paper_point.cost_saving_frac")],
-    # observability overhead: instrumented-vs-bare serving throughput in
-    # the same run, interleaved rounds (absolute speed cancels).  The
-    # committed ratio must stay ~1.0 — obs on the hot path is required
-    # to be within noise of obs off
-    "obs_overhead": [Gate("throughput_ratio")],
 }
 
 BENCH_ENV = {
@@ -159,10 +154,6 @@ BENCH_ENV = {
                           "REPRO_BENCH_WORKERS": "4"},
     "frontier": {"REPRO_BENCH_IMAGES": "96",
                  "REPRO_BENCH_FRONTIER_HORIZON": "480"},
-    "obs_overhead": {"REPRO_BENCH_IMAGES": "120",
-                     "REPRO_BENCH_REQUESTS": "480",
-                     "REPRO_BENCH_MAX_BATCH": "16",
-                     "REPRO_BENCH_ROUNDS": "5"},
 }
 
 DEFAULT = ["subset_cache", "serving"]

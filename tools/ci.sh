@@ -16,7 +16,7 @@
 #                          compares fresh subset_cache/lattice/serving/
 #                          train_driver/scenarios/serving_mp/
 #                          serving_socket/serving_scenarios/roofline/
-#                          frontier/obs_overhead numbers
+#                          frontier numbers
 #                          against the committed benchmarks/results/*.json
 #                          baselines; REPRO_BENCH_TOLERANCE overrides the
 #                          30% gate on noisy runners)
@@ -184,7 +184,7 @@ if [[ "$FULL" == 1 ]]; then
     echo "== benchmark regression gates (fresh vs committed baselines) =="
     python tools/check_bench.py subset_cache lattice serving \
         train_driver scenarios serving_mp serving_socket \
-        serving_scenarios roofline frontier obs_overhead
+        serving_scenarios roofline frontier
 elif [[ "$HYGIENE" == 1 ]]; then
     echo "== subset-cache smoke benchmark (50 images) =="
     # scratch results dir: the committed baselines under benchmarks/
