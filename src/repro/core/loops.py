@@ -56,10 +56,10 @@ def _make_batch_select(agent, *, deterministic: bool):
     """(T, D) states -> (T, N) actions in one forward when possible.
 
     Prefers a dedicated ``select_action_batch`` (PPO's scalar-logp
-    ``select_action`` can't batch); otherwise probes whether the plain
-    action head is batch-polymorphic — at most once, since a failed probe
-    wastes a forward AND consumes an agent rng key — and falls back to
-    row-wise calls (e.g. Wolpertinger re-ranking)."""
+    ``select_action`` can't batch; SAC's reads back only the actions);
+    otherwise probes whether the plain action head is batch-polymorphic
+    — at most once, since a failed probe wastes a forward AND consumes
+    an agent rng key — and falls back to row-wise calls."""
     batch_fn = getattr(agent, "select_action_batch", None)
     batched = None
 

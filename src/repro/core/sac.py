@@ -110,21 +110,30 @@ def _update(cfg: SACConfig, state: SACState, batch) -> tuple:
 _update_block = scan_update_block(_update)
 
 
-@partial(jax.jit, static_argnums=0)
-def _act(cfg: SACConfig, state: SACState, s, deterministic: bool):
-    key, sub = jax.random.split(state.key)
-    proto_s, _ = nets.sample_action(state.actor, s, sub)
-    proto_d = nets.mean_action(state.actor, s)
-    proto = jnp.where(deterministic, proto_d, proto_s)
+@partial(jax.jit, static_argnums=(0, 4))
+def _act(cfg: SACConfig, actor, key, s, deterministic: bool, critics=None):
+    """Actions for ``s``: ``(a, proto, next_key)``.  Takes only what the
+    action reads (the actor, the key, and the critics ``(q1, q2)`` when
+    ``cfg.wolpertinger_k`` re-ranks) and returns no state: the targets
+    and optimiser moments never enter the served program.  The key is
+    split once a call in both modes; the deterministic program draws no
+    sample."""
+    key, sub = jax.random.split(key)
+    if deterministic:
+        proto = nets.mean_action(actor, s)
+    else:
+        proto, _ = nets.sample_action(actor, s, sub)
     if cfg.wolpertinger_k:
+        q1, q2 = critics
+
         def q_fn(st, actions):
             sr = jnp.broadcast_to(st, (actions.shape[0], st.shape[-1]))
-            return jnp.minimum(nets.q_value(state.q1, sr, actions),
-                               nets.q_value(state.q2, sr, actions))
+            return jnp.minimum(nets.q_value(q1, sr, actions),
+                               nets.q_value(q2, sr, actions))
         from repro.core.action_space import wolpertinger_select
         a = wolpertinger_select(proto, s, q_fn, k=cfg.wolpertinger_k)
-        return a, proto, state._replace(key=key)
-    return threshold_map(proto), proto, state._replace(key=key)
+        return a, proto, key
+    return threshold_map(proto), proto, key
 
 
 class SAC:
@@ -134,10 +143,29 @@ class SAC:
         self.cfg = cfg
         self.state = _init_state(cfg)
 
+    def _forward(self, s, deterministic):
+        st = self.state
+        critics = (st.q1, st.q2) if self.cfg.wolpertinger_k else None
+        a, proto, key = _act(self.cfg, st.actor, st.key, s,
+                             bool(deterministic), critics)
+        self.state = st._replace(key=key)
+        return a, proto
+
     def select_action(self, s: np.ndarray, *, deterministic=False):
-        a, proto, self.state = _act(self.cfg, self.state, jnp.asarray(s),
-                                    deterministic)
+        a, proto = self._forward(s, deterministic)
         return np.asarray(a), np.asarray(proto)
+
+    def select_action_batch(self, states: np.ndarray, *,
+                            deterministic=False):
+        """Actions only, for serving and evaluation: ``(actions, None)``,
+        (T, N) for (T, D) states, (N,) for one (D,) state.  One ``_act``
+        call and one readback; the proto action stays on the device.  The
+        critic re-rank scores one state a call, so with
+        ``wolpertinger_k`` a batch is taken row by row."""
+        if self.cfg.wolpertinger_k and np.ndim(states) > 1:
+            return np.stack([self.select_action_batch(
+                s, deterministic=deterministic)[0] for s in states]), None
+        return np.asarray(self._forward(states, deterministic)[0]), None
 
     def update(self, batch: Dict[str, np.ndarray]) -> Dict[str, float]:
         jb = {k: jnp.asarray(v) for k, v in batch.items()}

@@ -179,8 +179,8 @@ class AsyncFederationService:
         # histogram + span of a sampled request); with obs off every
         # phase is the shared no-op span and no clock is read
         self._phase = obs.phase if self._obs_on else no_phase
-        self._h_policy = self._h_shard_wait = self._h_iou_table = \
-            self._h_assemble_cpu = None
+        self._h_policy = self._h_policy_cpu = self._h_shard_wait = \
+            self._h_iou_table = self._h_assemble_cpu = None
         if self._obs_on:
             self._h_flush_size = self._metrics.histogram(
                 "serving.flush_size",
@@ -191,6 +191,9 @@ class AsyncFederationService:
             # the flush's decision: feature gather, padding, forward,
             # actions on the host
             self._h_policy = self._metrics.histogram("serving.policy_ms")
+            # ... and the dispatcher thread's CPU time in it
+            self._h_policy_cpu = self._metrics.histogram(
+                "serving.policy_cpu_ms")
             # handed to a shard thread -> that thread starts on it
             self._h_shard_wait = self._metrics.histogram(
                 "serving.shard_wait_ms")
@@ -375,7 +378,8 @@ class AsyncFederationService:
             else:
                 core = self.transport.core_at(clock)
                 self.core = core
-        with self._phase("policy", self._h_policy):
+        with self._phase("policy", self._h_policy,
+                         cpu_hist=self._h_policy_cpu):
             actions = self._decide(imgs, clock)
         with self._cv:      # counters race with reset_stats() otherwise
             self._stat["flushes"].inc()

@@ -83,9 +83,12 @@ def test_sac_act_and_update_block_compile_at_paper_widths(one_chip):
     assert cfg.hidden == (256, 256)
     state = jax.tree.map(lambda x: _spec(x.shape, one_chip, x.dtype),
                          jax.eval_shape(lambda: _init_state(cfg)))
-    states = _spec((16, cfg.state_dim), one_chip)
-    det = _spec((), one_chip, jnp.bool_)
-    _act.lower(cfg, state, states, det).compile()
+    # the served program: actor and key in, (a, proto, key) out, the
+    # mode static; one row and a full flush of 16
+    for rows in ((), (16,)):
+        states = _spec((*rows, cfg.state_dim), one_chip)
+        for det in (True, False):
+            _act.lower(cfg, state.actor, state.key, states, det).compile()
     K, B = 50, 256
     batches = {"s": _spec((K, B, cfg.state_dim), one_chip),
                "a": _spec((K, B, cfg.n_providers), one_chip),
