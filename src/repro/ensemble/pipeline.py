@@ -15,7 +15,7 @@ single per-image IoU matrix across all candidate provider subsets.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Union
+from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -114,14 +114,34 @@ def ensemble_detections(per_provider: Sequence[Detections], *,
                                 use_kernel=use_kernel)
 
 
+# The kernel path launches on a fixed set of (images, boxes) shapes, the
+# same for every roster and seed, so a service warms a bounded grid: box
+# counts rise to the next multiple of IOU_BOX_STEP, image counts to the
+# next power of two up to IOU_IMAGE_STEP and to its next multiple above.
+IOU_BOX_STEP = 8
+IOU_IMAGE_STEP = 16
+
+
+def iou_launch_shape(n_images: int, n_boxes: int) -> Tuple[int, int]:
+    """The padded (images, boxes) of a kernel launch over ``n_images``
+    images whose largest box count is ``n_boxes``."""
+    n_images, n_boxes = max(int(n_images), 1), max(int(n_boxes), 1)
+    if n_images <= IOU_IMAGE_STEP:
+        b = 1 << (n_images - 1).bit_length()
+    else:
+        b = -(-n_images // IOU_IMAGE_STEP) * IOU_IMAGE_STEP
+    return b, -(-n_boxes // IOU_BOX_STEP) * IOU_BOX_STEP
+
+
 def batch_iou_matrices(boxes_list: Sequence[np.ndarray], *,
                        use_kernel: Union[bool, str] = "auto"
                        ) -> List[np.ndarray]:
     """Pairwise self-IoU for a batch of images in one launch.
 
-    Kernel path pads every image's boxes to the batch max and runs a single
-    vmapped Pallas call (one compile per padded shape); numpy path computes
-    per image (padding would cost more than it saves on CPU).
+    Kernel path pads the batch to ``iou_launch_shape`` (zero boxes and
+    zero images, sliced off again) and runs a single vmapped Pallas call,
+    one compile per padded shape; numpy path computes per image (padding
+    would cost more than it saves on CPU).
     """
     from repro.ensemble.boxes import iou_matrix
     if not boxes_list:
@@ -132,7 +152,8 @@ def batch_iou_matrices(boxes_list: Sequence[np.ndarray], *,
         nmax = max(int(b.shape[0]) for b in boxes_list)
         if nmax == 0:
             return [np.zeros((0, 0), np.float32) for _ in boxes_list]
-        padded = np.zeros((len(boxes_list), nmax, 4), np.float32)
+        padded = np.zeros(iou_launch_shape(len(boxes_list), nmax) + (4,),
+                          np.float32)
         for i, b in enumerate(boxes_list):
             padded[i, :len(b)] = b
         full = np.asarray(batch_self_iou(

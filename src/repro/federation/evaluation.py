@@ -196,23 +196,34 @@ class SubsetEvaluationCore:
             t = self._tables[img_idx] = self._build_table(img_idx)
         return t
 
-    def precompute(self, img_indices: Sequence[int]) -> None:
+    def precompute(self, img_indices: Sequence[int]) -> Tuple[int, int]:
         """Build tables for many images; IoU matrices go through one batched
-        kernel launch on the kernel path."""
-        missing = [int(i) for i in img_indices if int(i) not in self._tables]
+        kernel launch on the kernel path.  Returns the IoU entries that
+        launch computed (its padded images x boxes^2) and those its tables
+        use (the sum of each table's boxes^2); ``(0, 0)`` where nothing
+        was launched."""
+        # an image asked twice in one call is built (and counted) once
+        missing = list(dict.fromkeys(
+            int(i) for i in img_indices if int(i) not in self._tables))
         if not missing:
-            return
-        if self.use_kernel:
-            from repro.ensemble.pipeline import batch_iou_matrices
-            boxes_list = [
-                np.concatenate([d.boxes for d in self.traces.dets[i]],
-                               axis=0) for i in missing]
-            ious = batch_iou_matrices(boxes_list, use_kernel=True)
-            for i, iou in zip(missing, ious):
-                self._tables[i] = self._build_table(i, iou=iou)
-        else:
+            return 0, 0
+        if not self.use_kernel:
             for i in missing:
                 self._tables[i] = self._build_table(i)
+            return 0, 0
+        from repro.ensemble.pipeline import (batch_iou_matrices,
+                                             iou_launch_shape)
+        boxes_list = [
+            np.concatenate([d.boxes for d in self.traces.dets[i]],
+                           axis=0) for i in missing]
+        ious = batch_iou_matrices(boxes_list, use_kernel=True)
+        for i, iou in zip(missing, ious):
+            self._tables[i] = self._build_table(i, iou=iou)
+        counts = [len(b) for b in boxes_list]
+        if not max(counts):
+            return 0, 0
+        b, m = iou_launch_shape(len(counts), max(counts))
+        return b * m * m, sum(n * n for n in counts)
 
     # -- memoized single-pair evaluation --------------------------------
     def mask_of(self, action: np.ndarray) -> int:
@@ -754,9 +765,13 @@ class ShardedSubsetEvaluationCore:
     def mask_of(self, action: np.ndarray) -> int:
         return action_to_mask(action)
 
-    def precompute(self, img_indices: Sequence[int]) -> None:
+    def precompute(self, img_indices: Sequence[int]) -> Tuple[int, int]:
+        """One launch per shard; the entries launched and used, summed."""
+        launched = used = 0
         for sid, imgs in self.partition(img_indices).items():
-            self.shards[sid].precompute(imgs)
+            la, us = self.shards[sid].precompute(imgs)
+            launched, used = launched + la, used + us
+        return launched, used
 
     def ensemble(self, img_idx: int, mask: int) -> Detections:
         return self.shard_of(img_idx).ensemble(img_idx, mask)

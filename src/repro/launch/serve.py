@@ -21,6 +21,10 @@ with ``--hosts host:port,...`` joins externally started
 ``repro.launch.shard_host`` servers — the multi-HOST path, see
 ``docs/serving.md``.
 
+``--roster {tab2,tab3}`` picks the provider roster: the paper's Tab. II
+three (AWS, Azure, Google; the default) or its Tab. III ten (those,
+Alibaba and MLaaS 4-9), on every path, the scenarios included.
+
 ``--policy {rl,cascade,mct,hybrid}`` swaps the subset-selection policy
 (the RL agent vs the ``repro.selection`` strategies; see
 ``docs/policies.md``); all four serve through the identical accounting
@@ -51,10 +55,18 @@ class FederationRun:
     shard_metrics: dict = field(default_factory=dict)
 
 
+ROSTERS = {"tab2": "default_providers", "tab3": "scalability_providers"}
+
+
+def roster_providers(name: str) -> list:
+    """The provider profiles of roster ``name`` (``ROSTERS``)."""
+    from repro.federation import providers
+    return getattr(providers, ROSTERS[name])()
+
+
 def run_federation(args) -> FederationRun:
     from repro.core.sac import SAC, SACConfig
     from repro.federation.env import ArmolEnv
-    from repro.federation.providers import default_providers
     from repro.federation.traces import generate_traces
     from repro.obs.metrics import merge_snapshots
     from repro.serving.async_service import AsyncFederationService
@@ -69,7 +81,7 @@ def run_federation(args) -> FederationRun:
     if args.scenario:
         from repro.scenarios import (DynamicProviderPool,
                                      NonStationaryArmolEnv, build_scenario)
-        providers = default_providers()
+        providers = roster_providers(args.roster)
         schedule = build_scenario(args.scenario, providers,
                                   horizon=max(args.requests, 2),
                                   seed=args.seed)
@@ -79,8 +91,8 @@ def run_federation(args) -> FederationRun:
         env = NonStationaryArmolEnv(pool, mode="gt", beta=0.0,
                                     observe_pool=False, seed=args.seed + 1)
     else:
-        traces = generate_traces(default_providers(), args.images,
-                                 seed=args.seed)
+        traces = generate_traces(roster_providers(args.roster),
+                                 args.images, seed=args.seed)
         env = ArmolEnv(traces, mode="gt", beta=0.0, seed=args.seed + 1)
     if args.policy == "rl":
         agent = SAC(SACConfig(state_dim=env.state_dim,
@@ -113,7 +125,8 @@ def run_federation(args) -> FederationRun:
         topts = {"hosts": [hp.strip() for hp in args.hosts.split(",")
                            if hp.strip()]}
     mode = (f"async/{transport}" if args.use_async else "sync")
-    print(f"[serve] federation ({mode}, policy={args.policy}): "
+    print(f"[serve] federation ({mode}, policy={args.policy}, "
+          f"roster={args.roster}): "
           f"{env.n_providers} providers, "
           f"{args.images} images, {args.requests} requests"
           + (f", scenario={args.scenario}" if args.scenario else ""))
@@ -225,6 +238,10 @@ def _parser() -> argparse.ArgumentParser:
                          "depth (deeper queue -> flush sooner)")
     ap.add_argument("--images", type=int, default=120,
                     help="federation: trace-set size")
+    ap.add_argument("--roster", default="tab2", choices=tuple(ROSTERS),
+                    help="federation: the provider roster — the paper's "
+                         "Tab. II three (AWS, Azure, Google) or its "
+                         "Tab. III ten (those, Alibaba, MLaaS 4-9)")
     ap.add_argument("--policy", default="rl",
                     choices=("rl", "cascade", "mct", "hybrid"),
                     help="federation: subset-selection policy — the RL "

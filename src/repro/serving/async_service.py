@@ -203,6 +203,17 @@ class AsyncFederationService:
                 "serving.iou_table_ms")
             self._h_assemble_cpu = self._metrics.histogram(
                 "serving.assemble_cpu_ms")
+            # thread plane: IoU entries the shard launches computed
+            # (padded images x boxes^2) and those their tables use
+            self._c_iou_launched = self._metrics.counter(
+                "serving.iou_entries_launched")
+            self._c_iou_used = self._metrics.counter(
+                "serving.iou_entries_used")
+            # providers each request's action selects
+            self._h_providers = self._metrics.histogram(
+                "serving.providers_selected",
+                bounds=tuple(float(b) for b in range(
+                    env.n_providers + 1)))
         # per-shard RPC latency histograms + condemned-shard counters
         # always land in the service's registry; worker-shipped spans
         # only when tracing is on (no-op for inline transports)
@@ -381,6 +392,9 @@ class AsyncFederationService:
         with self._phase("policy", self._h_policy,
                          cpu_hist=self._h_policy_cpu):
             actions = self._decide(imgs, clock)
+        if self._obs_on:
+            self._h_providers.observe_batch(
+                (np.asarray(actions) > 0.5).sum(axis=1).tolist())
         with self._cv:      # counters race with reset_stats() otherwise
             self._stat["flushes"].inc()
             self._stat[reason].inc()
@@ -452,7 +466,10 @@ class AsyncFederationService:
                 # one batched IoU launch per shard
                 with self._phase("iou_table", self._h_iou_table, tid,
                                  asm.span_id):
-                    shard.precompute(imgs)
+                    launched, used = shard.precompute(imgs)
+                if self._obs_on and launched:
+                    self._c_iou_launched.inc(launched)
+                    self._c_iou_used.inc(used)
                 with self._phase("fuse", None, tid, asm.span_id):
                     results = self._svc._account_batch(
                         imgs, actions, core=shard, costs=costs,

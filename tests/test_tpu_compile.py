@@ -77,6 +77,29 @@ def test_batched_iou_kernel_compiles(one_chip):
         _assert_kernel(compiled)
 
 
+def test_every_iou_launch_shape_of_a_flush_compiles(one_chip):
+    """The padded shapes a shard's flush of 1..16 images reaches up to 96
+    boxes (the Tab. III warm grid): at most 64 programs, each a kernel."""
+    from repro.ensemble.pipeline import iou_launch_shape
+    from repro.kernels.iou_matrix.ops import batch_self_iou
+    shapes = {iou_launch_shape(b, m) for b in range(1, 17)
+              for m in range(1, 97)}
+    assert len(shapes) <= 64
+    for b, m in sorted(shapes):
+        compiled = batch_self_iou.lower(_spec((b, m, 4), one_chip)).compile()
+        _assert_kernel(compiled)
+
+
+def test_sac_act_compiles_for_the_ten_provider_roster(one_chip):
+    from repro.core.sac import SACConfig, _act, _init_state
+    cfg = SACConfig(state_dim=144, n_providers=10)
+    state = jax.tree.map(lambda x: _spec(x.shape, one_chip, x.dtype),
+                         jax.eval_shape(lambda: _init_state(cfg)))
+    for rows in ((), (16,)):
+        _act.lower(cfg, state.actor, state.key,
+                   _spec((*rows, cfg.state_dim), one_chip), True).compile()
+
+
 def test_sac_act_and_update_block_compile_at_paper_widths(one_chip):
     from repro.core.sac import SACConfig, _act, _init_state, _update_block
     cfg = SACConfig(state_dim=144, n_providers=3)
