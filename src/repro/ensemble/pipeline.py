@@ -118,8 +118,13 @@ def ensemble_detections(per_provider: Sequence[Detections], *,
 # same for every roster and seed, so a service warms a bounded grid: box
 # counts rise to the next multiple of IOU_BOX_STEP, image counts to the
 # next power of two up to IOU_IMAGE_STEP and to its next multiple above.
+# A launch carries at least IOU_MIN_IMAGES images: a one-image batch is
+# compiled apart and, on the CPU, rounds some entries one ulp away from a
+# batched launch, while from two images up an image's table has the same
+# bits whatever else its launch carries.
 IOU_BOX_STEP = 8
 IOU_IMAGE_STEP = 16
+IOU_MIN_IMAGES = 2
 
 
 def iou_launch_shape(n_images: int, n_boxes: int) -> Tuple[int, int]:
@@ -127,10 +132,31 @@ def iou_launch_shape(n_images: int, n_boxes: int) -> Tuple[int, int]:
     images whose largest box count is ``n_boxes``."""
     n_images, n_boxes = max(int(n_images), 1), max(int(n_boxes), 1)
     if n_images <= IOU_IMAGE_STEP:
-        b = 1 << (n_images - 1).bit_length()
+        b = max(1 << (n_images - 1).bit_length(), IOU_MIN_IMAGES)
     else:
         b = -(-n_images // IOU_IMAGE_STEP) * IOU_IMAGE_STEP
     return b, -(-n_boxes // IOU_BOX_STEP) * IOU_BOX_STEP
+
+
+def launch_self_iou(boxes_list: Sequence[np.ndarray]):
+    """Dispatch the kernel path's one launch over a batch of images
+    without waiting for it: the batch is padded to ``iou_launch_shape``
+    (zero boxes and zero images), the vmapped Pallas call is issued and
+    its copy to the host started.  Returns the padded (images, boxes,
+    boxes) device array, whose ``[i, :n_i, :n_i]`` is image ``i``'s IoU,
+    or ``None`` where no image has a box (nothing is launched)."""
+    import jax
+    from repro.kernels.iou_matrix.ops import batch_self_iou
+    nmax = max((int(b.shape[0]) for b in boxes_list), default=0)
+    if nmax == 0:
+        return None
+    padded = np.zeros(iou_launch_shape(len(boxes_list), nmax) + (4,),
+                      np.float32)
+    for i, b in enumerate(boxes_list):
+        padded[i, :len(b)] = b
+    full = batch_self_iou(padded, interpret=jax.default_backend() == "cpu")
+    full.copy_to_host_async()
+    return full
 
 
 def batch_iou_matrices(boxes_list: Sequence[np.ndarray], *,
@@ -138,26 +164,18 @@ def batch_iou_matrices(boxes_list: Sequence[np.ndarray], *,
                        ) -> List[np.ndarray]:
     """Pairwise self-IoU for a batch of images in one launch.
 
-    Kernel path pads the batch to ``iou_launch_shape`` (zero boxes and
-    zero images, sliced off again) and runs a single vmapped Pallas call,
-    one compile per padded shape; numpy path computes per image (padding
-    would cost more than it saves on CPU).
+    Kernel path: one ``launch_self_iou`` (one compile per padded shape),
+    read back at once; numpy path computes per image (padding would cost
+    more than it saves on CPU).
     """
     from repro.ensemble.boxes import iou_matrix
     if not boxes_list:
         return []
     if resolve_use_kernel(use_kernel):
-        import jax
-        from repro.kernels.iou_matrix.ops import batch_self_iou
-        nmax = max(int(b.shape[0]) for b in boxes_list)
-        if nmax == 0:
+        full = launch_self_iou(boxes_list)
+        if full is None:
             return [np.zeros((0, 0), np.float32) for _ in boxes_list]
-        padded = np.zeros(iou_launch_shape(len(boxes_list), nmax) + (4,),
-                          np.float32)
-        for i, b in enumerate(boxes_list):
-            padded[i, :len(b)] = b
-        full = np.asarray(batch_self_iou(
-            padded, interpret=jax.default_backend() == "cpu"))
+        full = np.asarray(full)
         return [full[i, :len(b), :len(b)] for i, b in enumerate(boxes_list)]
     return [iou_matrix(b, b) if len(b) else np.zeros((0, 0), np.float32)
             for b in boxes_list]

@@ -196,34 +196,50 @@ class SubsetEvaluationCore:
             t = self._tables[img_idx] = self._build_table(img_idx)
         return t
 
-    def precompute(self, img_indices: Sequence[int]) -> Tuple[int, int]:
+    def all_boxes(self, img_idx: int) -> np.ndarray:
+        """The image's boxes over all providers, in table row order."""
+        return np.concatenate(
+            [d.boxes for d in self.traces.dets[int(img_idx)]], axis=0)
+
+    def precompute(self, img_indices: Sequence[int],
+                   ious: Optional[Dict[int, np.ndarray]] = None
+                   ) -> Tuple[int, int]:
         """Build tables for many images; IoU matrices go through one batched
-        kernel launch on the kernel path.  Returns the IoU entries that
-        launch computed (its padded images x boxes^2) and those its tables
-        use (the sum of each table's boxes^2); ``(0, 0)`` where nothing
-        was launched."""
+        kernel launch on the kernel path.  ``ious`` maps image ids to IoU
+        matrices computed elsewhere (a flush's one launch): those images'
+        tables are built from them, and only the images it lacks are
+        launched for.  Returns the IoU entries this call's launch computed
+        (its padded images x boxes^2, 0 where nothing was launched) and
+        those its tables use (the sum of each table's boxes^2)."""
         # an image asked twice in one call is built (and counted) once
         missing = list(dict.fromkeys(
             int(i) for i in img_indices if int(i) not in self._tables))
+        used = 0
+        if ious:
+            given = [i for i in missing if i in ious]
+            for i in given:
+                # a copy: the table must not hold the whole launch
+                self._tables[i] = self._build_table(
+                    i, iou=np.ascontiguousarray(ious[i]))
+                used += ious[i].size
+            missing = [i for i in missing if i not in ious]
         if not missing:
-            return 0, 0
+            return 0, used
         if not self.use_kernel:
             for i in missing:
                 self._tables[i] = self._build_table(i)
-            return 0, 0
+            return 0, used
         from repro.ensemble.pipeline import (batch_iou_matrices,
                                              iou_launch_shape)
-        boxes_list = [
-            np.concatenate([d.boxes for d in self.traces.dets[i]],
-                           axis=0) for i in missing]
-        ious = batch_iou_matrices(boxes_list, use_kernel=True)
-        for i, iou in zip(missing, ious):
+        boxes_list = [self.all_boxes(i) for i in missing]
+        for i, iou in zip(missing,
+                          batch_iou_matrices(boxes_list, use_kernel=True)):
             self._tables[i] = self._build_table(i, iou=iou)
         counts = [len(b) for b in boxes_list]
         if not max(counts):
-            return 0, 0
+            return 0, used
         b, m = iou_launch_shape(len(counts), max(counts))
-        return b * m * m, sum(n * n for n in counts)
+        return b * m * m, used + sum(n * n for n in counts)
 
     # -- memoized single-pair evaluation --------------------------------
     def mask_of(self, action: np.ndarray) -> int:
@@ -702,6 +718,27 @@ class SubsetEvaluationCore:
         return sorted(self._tables)
 
 
+@dataclass
+class PendingIou:
+    """A dispatched IoU launch over a flush's untabled images: the padded
+    device result (its host copy already started) and, per image in
+    launch order, its id and box count."""
+    imgs: List[int]
+    counts: List[int]
+    result: object          # (B, M, M) jax array
+
+    @property
+    def launched(self) -> int:
+        """IoU entries the launch computes: padded images x boxes^2."""
+        return int(np.prod(self.result.shape))
+
+    def matrices(self) -> Dict[int, np.ndarray]:
+        """Image id -> its (n, n) IoU matrix; waits for the host copy."""
+        full = np.asarray(self.result)
+        return {i: full[k, :n, :n]
+                for k, (i, n) in enumerate(zip(self.imgs, self.counts))}
+
+
 class ShardedSubsetEvaluationCore:
     """W shared-nothing ``SubsetEvaluationCore`` shards keyed by
     ``img_idx % W``.
@@ -765,6 +802,10 @@ class ShardedSubsetEvaluationCore:
     def mask_of(self, action: np.ndarray) -> int:
         return action_to_mask(action)
 
+    @property
+    def use_kernel(self) -> bool:
+        return self.shards[0].use_kernel
+
     def precompute(self, img_indices: Sequence[int]) -> Tuple[int, int]:
         """One launch per shard; the entries launched and used, summed."""
         launched = used = 0
@@ -772,6 +813,26 @@ class ShardedSubsetEvaluationCore:
             la, us = self.shards[sid].precompute(imgs)
             launched, used = launched + la, used + us
         return launched, used
+
+    def launch_iou(self, img_indices: Sequence[int]) -> Optional[PendingIou]:
+        """The kernel path's one IoU launch for a whole flush, across
+        shards: the images not yet in their home shard's table memo, each
+        once, padded with ``iou_launch_shape`` and dispatched without
+        waiting.  ``None`` where nothing is launched: every image tabled,
+        or no image with a box.  Only reads the memos, so a caller on
+        another thread than the shards' may issue it; an image whose table
+        is being built meanwhile is launched again and built once."""
+        imgs = list(dict.fromkeys(
+            int(i) for i in img_indices
+            if int(i) not in self.shard_of(i)._tables))
+        if not imgs:
+            return None
+        from repro.ensemble.pipeline import launch_self_iou
+        boxes = [self.shard_of(i).all_boxes(i) for i in imgs]
+        result = launch_self_iou(boxes)
+        if result is None:
+            return None
+        return PendingIou(imgs, [len(b) for b in boxes], result)
 
     def ensemble(self, img_idx: int, mask: int) -> Detections:
         return self.shard_of(img_idx).ensemble(img_idx, mask)

@@ -10,7 +10,9 @@ turns the service into an open system:
     and flushes when ``max_batch`` are waiting or the oldest has waited
     ``max_wait_ms``.  Each flush costs ONE batched agent forward (the
     whole point: the per-call jit dispatch overhead is amortized over the
-    flush) and one batched IoU precompute per touched shard.
+    flush) and, on the thread plane's kernel path, ONE IoU launch for
+    the flush's fresh images, issued by the dispatcher ahead of the
+    forward, so the shard threads build tables from host arrays.
   * **sharded caches behind a transport** — the subset-evaluation memo
     is split across W shared-nothing shards, each owned by one
     dispatcher-side thread.  The evaluation plane is pluggable
@@ -55,7 +57,7 @@ from repro.serving.transports import ShardTransport, get_transport
 # the dict-shaped stats contract: key order and names are part of the
 # public accessor (tests and benches read these directly)
 _STAT_KEYS = ("requests", "flushes", "batched_requests", "max_flush",
-              "flush_full", "flush_timeout", "flush_drain")
+              "flush_full", "flush_timeout", "flush_drain", "iou_launches")
 
 
 class AsyncFederationService:
@@ -180,7 +182,8 @@ class AsyncFederationService:
         # phase is the shared no-op span and no clock is read
         self._phase = obs.phase if self._obs_on else no_phase
         self._h_policy = self._h_policy_cpu = self._h_shard_wait = \
-            self._h_iou_table = self._h_assemble_cpu = None
+            self._h_iou_table = self._h_assemble_cpu = \
+            self._h_iou_launch = None
         if self._obs_on:
             self._h_flush_size = self._metrics.histogram(
                 "serving.flush_size",
@@ -194,6 +197,10 @@ class AsyncFederationService:
             # ... and the dispatcher thread's CPU time in it
             self._h_policy_cpu = self._metrics.histogram(
                 "serving.policy_cpu_ms")
+            # thread plane: the dispatcher's IoU launch for the flush and,
+            # after the decision, its readback (one observation each)
+            self._h_iou_launch = self._metrics.histogram(
+                "serving.iou_launch_ms")
             # handed to a shard thread -> that thread starts on it
             self._h_shard_wait = self._metrics.histogram(
                 "serving.shard_wait_ms")
@@ -203,8 +210,8 @@ class AsyncFederationService:
                 "serving.iou_table_ms")
             self._h_assemble_cpu = self._metrics.histogram(
                 "serving.assemble_cpu_ms")
-            # thread plane: IoU entries the shard launches computed
-            # (padded images x boxes^2) and those their tables use
+            # thread plane: IoU entries the launches computed (padded
+            # images x boxes^2) and those the tables built use
             self._c_iou_launched = self._metrics.counter(
                 "serving.iou_entries_launched")
             self._c_iou_used = self._metrics.counter(
@@ -389,13 +396,26 @@ class AsyncFederationService:
             else:
                 core = self.transport.core_at(clock)
                 self.core = core
+        pending = ious = None
+        if self.transport.inline and core.use_kernel:
+            # the flush's one IoU launch, queued on the device ahead of
+            # the forward; its readback waits until the decision is back
+            with self._phase("iou_launch", self._h_iou_launch):
+                pending = core.launch_iou(imgs)
         with self._phase("policy", self._h_policy,
                          cpu_hist=self._h_policy_cpu):
             actions = self._decide(imgs, clock)
+        if pending is not None:
+            with self._phase("iou_launch", self._h_iou_launch):
+                ious = pending.matrices()
         if self._obs_on:
             self._h_providers.observe_batch(
                 (np.asarray(actions) > 0.5).sum(axis=1).tolist())
+            if pending is not None:
+                self._c_iou_launched.inc(pending.launched)
         with self._cv:      # counters race with reset_stats() otherwise
+            if pending is not None:
+                self._stat["iou_launches"].inc()
             self._stat["flushes"].inc()
             self._stat[reason].inc()
             self._stat["requests"].inc(len(batch))
@@ -431,7 +451,7 @@ class AsyncFederationService:
                     self._account_shard, core, sid,
                     [batch[p] for p in positions], actions[positions],
                     costs, lats, trace_ctx, log_ctx,
-                    time.perf_counter() if self._obs_on else 0.0)
+                    time.perf_counter() if self._obs_on else 0.0, ious)
 
     def _partition(self, imgs: np.ndarray):
         groups: dict = {}
@@ -450,9 +470,14 @@ class AsyncFederationService:
 
     def _account_shard(self, core, sid: int, items, actions: np.ndarray,
                        costs, lats, trace_ctx=None, log_ctx=None,
-                       t_submit: float = 0.0) -> None:
+                       t_submit: float = 0.0, ious=None) -> None:
         """Runs on shard ``sid``'s dedicated thread — the only thread that
-        ever touches that shard's dicts (for the flush's captured core)."""
+        ever writes that shard's dicts (for the flush's captured core).
+        The dispatcher only reads the table memo's membership, to choose
+        what the flush's launch covers; ``ious`` is that launch's host
+        matrices by image id.  An image whose table was in flight when the
+        dispatcher looked is delivered twice and built once; one that the
+        launch lacks is launched for here."""
         tid, parent = self._trace_parent(trace_ctx)
         try:
             if self._obs_on:
@@ -463,11 +488,15 @@ class AsyncFederationService:
                              n=len(items)) as asm:
                 shard = core.shards[sid]
                 imgs = [it[0] for it in items]
-                # one batched IoU launch per shard
+                # tables from the flush's launch; a launch here only for
+                # images it lacks
                 with self._phase("iou_table", self._h_iou_table, tid,
                                  asm.span_id):
-                    launched, used = shard.precompute(imgs)
-                if self._obs_on and launched:
+                    launched, used = shard.precompute(imgs, ious)
+                if launched:
+                    with self._cv:
+                        self._stat["iou_launches"].inc()
+                if self._obs_on and used:
                     self._c_iou_launched.inc(launched)
                     self._c_iou_used.inc(used)
                 with self._phase("fuse", None, tid, asm.span_id):
