@@ -816,7 +816,7 @@ def bench_serving_mp():
             for backend in ("thread", "process"):
                 svc = AsyncFederationService(
                     env, agent, max_batch=max_batch, max_wait_ms=2.0,
-                    workers=w, shard_backend=backend)
+                    workers=w, transport=backend)
                 svc.handle(reqs[0])          # single-request jit shape
                 svc.handle_many(reqs)        # batched jit shape + warm run
                 svcs[backend] = svc
@@ -1052,7 +1052,7 @@ def bench_serving_scenarios():
         reqs = [int(i) for i in rng.integers(0, n_images, n_reqs)]
         with AsyncFederationService(env, agent, max_batch=max_batch,
                                     max_wait_ms=2.0, workers=workers,
-                                    pool=pool, shard_backend=backend) as svc:
+                                    pool=pool, transport=backend) as svc:
             svc.handle(reqs[0])
             svc.handle_many(list(range(n_images)))   # warm shards + jit
             svc.reset_stats()

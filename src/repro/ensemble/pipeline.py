@@ -49,7 +49,6 @@ def ensemble_from_arrays(boxes: np.ndarray, scores: np.ndarray,
                          labels: np.ndarray, providers: np.ndarray,
                          n_selected: int, *, voting: str = "affirmative",
                          ablation: str = "wbf", iou_thr: float = 0.5,
-                         use_kernel: bool = False,
                          iou: Optional[np.ndarray] = None) -> Detections:
     """Array-first ensemble core: merged per-image arrays in, fused out.
 
@@ -63,8 +62,7 @@ def ensemble_from_arrays(boxes: np.ndarray, scores: np.ndarray,
     merged = Detections.fast(boxes, scores, labels, providers)
     if len(merged) == 0:
         return merged
-    groups = group_detections(merged, iou_thr=iou_thr,
-                              use_kernel=use_kernel, iou=iou)
+    groups = group_detections(merged, iou_thr=iou_thr, iou=iou)
     groups = vote_filter(merged, groups, method=voting,
                          n_selected=n_selected)
     if ablation == "wbf":
@@ -103,15 +101,16 @@ def ensemble_detections(per_provider: Sequence[Detections], *,
 
     ``per_provider[i]`` is provider i's detections for one image, with
     labels already mapped to canonical group ids by the word-grouping stage.
+    ``use_kernel`` takes the merged boxes' IoU from a kernel launch.
     """
     if not per_provider:
         return Detections.empty()
     boxes, scores, labels, providers = \
         merge_provider_detections(per_provider)
+    iou = batch_iou_matrices([boxes], use_kernel=use_kernel)[0]
     return ensemble_from_arrays(boxes, scores, labels, providers,
                                 len(per_provider), voting=voting,
-                                ablation=ablation, iou_thr=iou_thr,
-                                use_kernel=use_kernel)
+                                ablation=ablation, iou_thr=iou_thr, iou=iou)
 
 
 # The kernel path launches on a fixed set of (images, boxes) shapes, the

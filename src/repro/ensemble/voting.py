@@ -20,7 +20,6 @@ IOU_GROUP_THR = 0.5
 
 
 def group_detections(dets: Detections, *, iou_thr: float = IOU_GROUP_THR,
-                     use_kernel: bool = False,
                      iou: Optional[np.ndarray] = None) -> List[np.ndarray]:
     """Greedy clustering by (label, IoU>thr).  Returns index arrays.
 
@@ -28,20 +27,14 @@ def group_detections(dets: Detections, *, iou_thr: float = IOU_GROUP_THR,
     existing group whose *representative* (highest-score member) matches.
     ``iou`` supplies a precomputed (n, n) pairwise IoU matrix (the batched
     subset-evaluation core slices one kernel-backed matrix per image across
-    all candidate subsets); otherwise it is computed here. ``use_kernel=True``
-    routes that computation through the Pallas kernel wrapper (interpret
-    mode on CPU).
+    all candidate subsets); otherwise the numpy twin computes it here.
     """
     n = len(dets)
     if n == 0:
         return []
     order = np.argsort(-dets.scores, kind="stable").tolist()
     if iou is None:
-        if use_kernel:
-            from repro.kernels.iou_matrix.ops import iou_matrix_op
-            iou = np.asarray(iou_matrix_op(dets.boxes, dets.boxes))
-        else:
-            iou = iou_matrix(dets.boxes, dets.boxes)
+        iou = iou_matrix(dets.boxes, dets.boxes)
     # per-subset merged sets are small (tens of boxes): python-scalar greedy
     # over list-converted rows beats numpy-indexed scalars ~10x here
     iou_rows = iou.tolist()

@@ -23,7 +23,7 @@ Subsets are keyed by bitmask: bit i set <=> provider i selected, so the
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -170,30 +170,27 @@ class SubsetEvaluationCore:
                       "lattice_hits": 0, "lattice_misses": 0}
 
     # -- per-image table ------------------------------------------------
-    def _full_iou(self, boxes: np.ndarray) -> np.ndarray:
-        if len(boxes) == 0:
-            return np.zeros((0, 0), np.float32)
-        if self.use_kernel:
-            from repro.kernels.iou_matrix.ops import iou_matrix_op
-            return np.asarray(iou_matrix_op(boxes, boxes))
-        return iou_matrix(boxes, boxes)
-
     def _build_table(self, img_idx: int,
                      iou: Optional[np.ndarray] = None) -> _ImageTable:
+        """The image's table; ``iou`` is its launched IoU matrix, or
+        ``None`` on the numpy path, which computes it here."""
         dets = self.traces.dets[img_idx]
         lengths = np.asarray([len(d) for d in dets], np.int64)
         # full-set merge: positional tags coincide with true provider ids
         boxes, scores, labels, row_provider = \
             merge_provider_detections(dets)
         if iou is None:
-            iou = self._full_iou(boxes)
+            iou = iou_matrix(boxes, boxes) if len(boxes) else \
+                np.zeros((0, 0), np.float32)
         self.stats["tables"] += 1
         return _ImageTable(boxes, scores, labels, lengths, row_provider, iou)
 
     def table(self, img_idx: int) -> _ImageTable:
         t = self._tables.get(img_idx)
         if t is None:
-            t = self._tables[img_idx] = self._build_table(img_idx)
+            # one image takes the same padded launch as a flush
+            self.precompute([img_idx])
+            t = self._tables[img_idx]
         return t
 
     def all_boxes(self, img_idx: int) -> np.ndarray:
@@ -204,8 +201,8 @@ class SubsetEvaluationCore:
     def precompute(self, img_indices: Sequence[int],
                    ious: Optional[Dict[int, np.ndarray]] = None
                    ) -> Tuple[int, int]:
-        """Build tables for many images; IoU matrices go through one batched
-        kernel launch on the kernel path.  ``ious`` maps image ids to IoU
+        """Build tables for many images; on the kernel path their IoU
+        matrices come from one launch.  ``ious`` maps image ids to IoU
         matrices computed elsewhere (a flush's one launch): those images'
         tables are built from them, and only the images it lacks are
         launched for.  Returns the IoU entries this call's launch computed
@@ -214,32 +211,19 @@ class SubsetEvaluationCore:
         # an image asked twice in one call is built (and counted) once
         missing = list(dict.fromkeys(
             int(i) for i in img_indices if int(i) not in self._tables))
+        ious = dict(ious or {})
+        pending = launch_untabled(self, (i for i in missing if i not in ious))
+        if pending is not None:
+            ious.update(pending.matrices())
         used = 0
-        if ious:
-            given = [i for i in missing if i in ious]
-            for i in given:
+        for i in missing:
+            iou = ious.get(i)
+            if iou is not None:
                 # a copy: the table must not hold the whole launch
-                self._tables[i] = self._build_table(
-                    i, iou=np.ascontiguousarray(ious[i]))
-                used += ious[i].size
-            missing = [i for i in missing if i not in ious]
-        if not missing:
-            return 0, used
-        if not self.use_kernel:
-            for i in missing:
-                self._tables[i] = self._build_table(i)
-            return 0, used
-        from repro.ensemble.pipeline import (batch_iou_matrices,
-                                             iou_launch_shape)
-        boxes_list = [self.all_boxes(i) for i in missing]
-        for i, iou in zip(missing,
-                          batch_iou_matrices(boxes_list, use_kernel=True)):
-            self._tables[i] = self._build_table(i, iou=iou)
-        counts = [len(b) for b in boxes_list]
-        if not max(counts):
-            return 0, used
-        b, m = iou_launch_shape(len(counts), max(counts))
-        return b * m * m, used + sum(n * n for n in counts)
+                iou = np.ascontiguousarray(iou)
+                used += iou.size
+            self._tables[i] = self._build_table(i, iou)
+        return (0 if pending is None else pending.launched), used
 
     # -- memoized single-pair evaluation --------------------------------
     def mask_of(self, action: np.ndarray) -> int:
@@ -302,6 +286,13 @@ class SubsetEvaluationCore:
                 iou_thr=self.iou_thr, iou=t.iou[idx[:, None], idx])
         self._ens[key] = ens
         return ens
+
+    def ensembles(self, img_indices: Sequence[int],
+                  masks: Sequence[int]) -> List[Detections]:
+        """Memoized ensembles of (image, mask) rows, in order; an empty
+        mask answers empty without a lookup."""
+        return [self.ensemble(int(i), int(m)) if m else Detections.empty()
+                for i, m in zip(img_indices, masks)]
 
     def pseudo_gt(self, img_idx: int) -> Detections:
         """Ensemble of ALL providers — the w/o-gt reference (paper Sec. III)."""
@@ -720,9 +711,9 @@ class SubsetEvaluationCore:
 
 @dataclass
 class PendingIou:
-    """A dispatched IoU launch over a flush's untabled images: the padded
-    device result (its host copy already started) and, per image in
-    launch order, its id and box count."""
+    """A dispatched IoU launch over untabled images: the padded device
+    result (its host copy already started) and, per image in launch
+    order, its id and box count."""
     imgs: List[int]
     counts: List[int]
     result: object          # (B, M, M) jax array
@@ -730,13 +721,33 @@ class PendingIou:
     @property
     def launched(self) -> int:
         """IoU entries the launch computes: padded images x boxes^2."""
-        return int(np.prod(self.result.shape))
+        b, m, _ = self.result.shape
+        return b * m * m
 
     def matrices(self) -> Dict[int, np.ndarray]:
         """Image id -> its (n, n) IoU matrix; waits for the host copy."""
         full = np.asarray(self.result)
         return {i: full[k, :n, :n]
                 for k, (i, n) in enumerate(zip(self.imgs, self.counts))}
+
+
+def launch_untabled(core, imgs: Iterable[int]) -> Optional[PendingIou]:
+    """The one IoU launch for ``imgs`` (untabled image ids, each launched
+    once), padded with ``iou_launch_shape`` and dispatched without
+    waiting; ``core`` gives the boxes and the kernel/host decision, which
+    is read before ``imgs`` is.  ``None`` where nothing is launched: the
+    numpy path, no image, or no image with a box."""
+    if not core.use_kernel:
+        return None
+    imgs = list(dict.fromkeys(imgs))
+    if not imgs:
+        return None
+    from repro.ensemble.pipeline import launch_self_iou
+    boxes = [core.all_boxes(i) for i in imgs]
+    result = launch_self_iou(boxes)
+    if result is None:
+        return None
+    return PendingIou(imgs, [len(b) for b in boxes], result)
 
 
 class ShardedSubsetEvaluationCore:
@@ -817,22 +828,13 @@ class ShardedSubsetEvaluationCore:
     def launch_iou(self, img_indices: Sequence[int]) -> Optional[PendingIou]:
         """The kernel path's one IoU launch for a whole flush, across
         shards: the images not yet in their home shard's table memo, each
-        once, padded with ``iou_launch_shape`` and dispatched without
-        waiting.  ``None`` where nothing is launched: every image tabled,
-        or no image with a box.  Only reads the memos, so a caller on
+        once (``launch_untabled``).  Only reads the memos, so a caller on
         another thread than the shards' may issue it; an image whose table
         is being built meanwhile is launched again and built once."""
-        imgs = list(dict.fromkeys(
+        # every shard holds the same traces and configuration
+        return launch_untabled(self.shards[0], (
             int(i) for i in img_indices
             if int(i) not in self.shard_of(i)._tables))
-        if not imgs:
-            return None
-        from repro.ensemble.pipeline import launch_self_iou
-        boxes = [self.shard_of(i).all_boxes(i) for i in imgs]
-        result = launch_self_iou(boxes)
-        if result is None:
-            return None
-        return PendingIou(imgs, [len(b) for b in boxes], result)
 
     def ensemble(self, img_idx: int, mask: int) -> Detections:
         return self.shard_of(img_idx).ensemble(img_idx, mask)

@@ -15,10 +15,9 @@ cache shards / ensemble threads, flush at ``--max-batch`` requests or
   PYTHONPATH=src python -m repro.launch.serve --federation --async \
       --requests 600 --workers 4 --max-batch 16 --max-wait-ms 2
 
-``--transport {thread,process,socket}`` picks the evaluation plane
-(``--shard-backend`` is the deprecated alias); ``--transport socket``
-with ``--hosts host:port,...`` joins externally started
-``repro.launch.shard_host`` servers — the multi-HOST path, see
+``--transport {thread,process,socket}`` picks the evaluation plane;
+``--transport socket`` with ``--hosts host:port,...`` joins externally
+started ``repro.launch.shard_host`` servers — the multi-HOST path, see
 ``docs/serving.md``.
 
 ``--roster {tab2,tab3}`` picks the provider roster: the paper's Tab. II
@@ -111,13 +110,6 @@ def run_federation(args) -> FederationRun:
     rng = np.random.default_rng(args.seed)
     reqs = [int(i) for i in rng.integers(0, args.images, args.requests)]
     transport = args.transport
-    if transport is None:
-        if args.shard_backend is not None:
-            print("[serve] --shard-backend is deprecated; "
-                  "use --transport")
-            transport = args.shard_backend
-        else:
-            transport = "thread"
     topts = None
     if args.hosts:
         if transport != "socket":
@@ -212,7 +204,7 @@ def _parser() -> argparse.ArgumentParser:
                     help="micro-batching AsyncFederationService")
     ap.add_argument("--workers", type=int, default=4,
                     help="async: cache shards / ensemble worker threads")
-    ap.add_argument("--transport", default=None,
+    ap.add_argument("--transport", default="thread",
                     choices=("thread", "process", "socket"),
                     help="async: the evaluation plane — in-process "
                          "threads (zero IPC, GIL-bound assembly), one "
@@ -226,9 +218,6 @@ def _parser() -> argparse.ArgumentParser:
                          "addr:port of externally started shard hosts "
                          "(python -m repro.launch.shard_host); empty = "
                          "spawn --workers hosts locally")
-    ap.add_argument("--shard-backend", default=None,
-                    choices=("thread", "process"),
-                    help="DEPRECATED alias of --transport")
     ap.add_argument("--max-batch", type=int, default=16,
                     help="async: flush when this many requests queue")
     ap.add_argument("--max-wait-ms", type=float, default=2.0,

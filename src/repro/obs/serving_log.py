@@ -2,10 +2,10 @@
 
 This is the durable record the ROADMAP's off-policy-evaluation item
 needs — which subsets were paid for, at what fee, under which regime,
-and what the ensemble earned — written by BOTH ``FederationService``
-accounting paths (the thread/sync `_account_batch` and the
-process-backend `_results_from_ensembles` assembly), so every serving
-configuration produces the same record stream.
+and what the ensemble earned — written by ``FederationService``'s one
+accounting sequence (`_account_batch`, which every serving path and
+plane runs), so every serving configuration produces the same record
+stream.
 
 Record schema (one JSON object per line)::
 

@@ -10,9 +10,10 @@ turns the service into an open system:
     and flushes when ``max_batch`` are waiting or the oldest has waited
     ``max_wait_ms``.  Each flush costs ONE batched agent forward (the
     whole point: the per-call jit dispatch overhead is amortized over the
-    flush) and, on the thread plane's kernel path, ONE IoU launch for
-    the flush's fresh images, issued by the dispatcher ahead of the
-    forward, so the shard threads build tables from host arrays.
+    flush) and, where the transport launches one (the thread plane's
+    kernel path), ONE IoU launch for the flush's fresh images, issued by
+    the dispatcher ahead of the forward, so the shard threads build
+    tables from host arrays.
   * **sharded caches behind a transport** — the subset-evaluation memo
     is split across W shared-nothing shards, each owned by one
     dispatcher-side thread.  The evaluation plane is pluggable
@@ -23,14 +24,15 @@ turns the service into an open system:
     shard, off the GIL) or ``"socket"``
     (``SocketShardedSubsetEvaluationCore`` — H shard HOSTS over TCP with
     consistent-hash routing and health-checked requeue).  All planes
-    answer bit-identical results.  Accounting stays in the parent either
-    way (``FederationService._route_batch``); only ensemble rows cross
-    the transport boundary.
+    answer bit-identical results, and the service never asks which one
+    it runs on: every plane's shard part goes through the one
+    ``_account_shard`` (tables, then route, ensembles, results and log
+    in ``FederationService._account_batch``); only ensemble rows cross
+    an RPC transport's boundary.
   * **overlap** — the dispatcher hands each shard's slice of the flush to
-    that shard's worker and immediately returns to batching: provider
-    fan-out/ensemble assembly (the thread pool over the vectorized
-    ``_account_batch``; provider "inference" is parallel-latency in the
-    paper's model, Sec. II-B) overlaps the NEXT flush's agent forward.
+    that shard's thread and immediately returns to batching: ensemble
+    assembly (provider "inference" is parallel-latency in the paper's
+    model, Sec. II-B) overlaps the NEXT flush's agent forward.
 
 At ``max_batch=1, workers=1`` every request is its own flush through the
 same single-state ``select_action`` call ``handle`` makes, so results are
@@ -40,9 +42,9 @@ from __future__ import annotations
 
 import threading
 import time
-import warnings
 from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
+from functools import partial
 from typing import List, Optional, Sequence, Union
 
 import numpy as np
@@ -79,9 +81,6 @@ class AsyncFederationService:
     transport_options: transport-specific knobs passed to the registry
                   build (the socket plane's ``hosts=["addr:port", ...]``
                   / health intervals).
-    shard_backend: DEPRECATED alias of ``transport`` (names resolve
-                  through the same registry); emits a
-                  ``DeprecationWarning``.
     mp_context:   multiprocessing start method for the process/socket
                   planes (``"spawn"`` default — the parent runs jax,
                   whose runtime threads do not survive ``fork``).
@@ -102,27 +101,11 @@ class AsyncFederationService:
                  transmission_ms: float = 20.0, max_batch: int = 16,
                  max_wait_ms: float = 2.0, workers: int = 2,
                  adaptive: bool = False, pool=None,
-                 transport: Union[str, ShardTransport, None] = None,
+                 transport: Union[str, ShardTransport] = "thread",
                  transport_options: Optional[dict] = None,
-                 shard_backend: Optional[str] = None,
                  mp_context: str = "spawn", obs=None):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
-        if shard_backend is not None:
-            # legacy string kwarg: same names, same registry, loud exit
-            # path.  Kept strict — the old surface only ever accepted
-            # these two values, so typos stay errors, not new planes.
-            warnings.warn(
-                "shard_backend= is deprecated; use transport="
-                "'thread'|'process'|'socket' (or a ShardTransport "
-                "instance) instead", DeprecationWarning, stacklevel=2)
-            if shard_backend not in ("thread", "process"):
-                raise ValueError(f"shard_backend must be 'thread' or "
-                                 f"'process', got {shard_backend!r}")
-            if transport is None:
-                transport = shard_backend
-        if transport is None:
-            transport = "thread"
         self.env = env
         self.agent = agent
         self.max_batch = int(max_batch)
@@ -132,10 +115,8 @@ class AsyncFederationService:
         # anything with view_at/sharded_core_at/snapshot_at): each flush
         # is accounted under the pool state at the service's scenario
         # clock, which advances one step per request — mid-stream regime
-        # swaps apply at flush boundaries, never inside one.  The inline
-        # (thread) plane swaps the whole sharded core; RPC planes keep
-        # ONE worker/host pool for the service's lifetime and ship each
-        # segment across the boundary as a PoolSnapshot recipe.
+        # swaps apply at flush boundaries, never inside one (the
+        # transport's ``segment`` names what a flush evaluates against).
         self.pool = pool
         self._scn_clock = 0
         if isinstance(transport, str):
@@ -143,7 +124,6 @@ class AsyncFederationService:
                 env=env, pool=pool, workers=int(workers),
                 mp_context=mp_context, options=transport_options)
         self.transport = transport
-        self.core = transport.core
         # the transport decides the real shard count (joined socket
         # hosts may outnumber ``workers``); one parent-side accounting
         # thread per shard id
@@ -197,21 +177,22 @@ class AsyncFederationService:
             # ... and the dispatcher thread's CPU time in it
             self._h_policy_cpu = self._metrics.histogram(
                 "serving.policy_cpu_ms")
-            # thread plane: the dispatcher's IoU launch for the flush and,
-            # after the decision, its readback (one observation each)
+            # the dispatcher's IoU launch for the flush and, after the
+            # decision, its readback (one observation each; a flush that
+            # launches nothing observes the launch step alone)
             self._h_iou_launch = self._metrics.histogram(
                 "serving.iou_launch_ms")
             # handed to a shard thread -> that thread starts on it
             self._h_shard_wait = self._metrics.histogram(
                 "serving.shard_wait_ms")
-            # thread plane: one shard part's IoU tables, and the thread
-            # CPU time of its whole assembly
+            # one shard part's IoU tables, and the thread CPU time of its
+            # whole assembly
             self._h_iou_table = self._metrics.histogram(
                 "serving.iou_table_ms")
             self._h_assemble_cpu = self._metrics.histogram(
                 "serving.assemble_cpu_ms")
-            # thread plane: IoU entries the launches computed (padded
-            # images x boxes^2) and those the tables built use
+            # IoU entries the launches computed (padded images x boxes^2)
+            # and those the tables built use
             self._c_iou_launched = self._metrics.counter(
                 "serving.iou_entries_launched")
             self._c_iou_used = self._metrics.counter(
@@ -223,7 +204,7 @@ class AsyncFederationService:
                     env.n_providers + 1)))
         # per-shard RPC latency histograms + condemned-shard counters
         # always land in the service's registry; worker-shipped spans
-        # only when tracing is on (no-op for inline transports)
+        # only when tracing is on (no-op for the thread transport)
         self.transport.bind_obs(self._metrics, self._tracer)
         self._shard_pools = [
             ThreadPoolExecutor(max_workers=1,
@@ -232,6 +213,12 @@ class AsyncFederationService:
         self._dispatcher = threading.Thread(target=self._dispatch_loop,
                                             name="fed-dispatch", daemon=True)
         self._dispatcher.start()
+
+    @property
+    def core(self):
+        """The transport's sharded core (on the thread plane with a pool,
+        the live segment's)."""
+        return self.transport.core
 
     # -- client surface --------------------------------------------------
     def submit(self, img_idx: int) -> "Future[FederationResult]":
@@ -381,41 +368,30 @@ class AsyncFederationService:
     def _flush_batch(self, batch, clock: int, reason: str, span) -> None:
         imgs = np.asarray([b[0] for b in batch], np.int64)
         costs = lats = None
-        snapshot = None
-        core = self.core
         if self.pool is not None:
-            # one consistent (core, fee/latency) snapshot per flush:
+            # one consistent (segment, fee/latency) snapshot per flush:
             # in-flight assembly keeps its captured segment even if the
             # clock crosses a boundary while it overlaps the next flush
             view = self.pool.view_at(clock)
             costs, lats = view.costs, view.latencies
-            if not self.transport.inline:
-                # the worker/host pool persists across segments; the
-                # segment rides along with each shard request as a recipe
-                snapshot = self.pool.snapshot_at(clock)
-            else:
-                core = self.transport.core_at(clock)
-                self.core = core
-        pending = ious = None
-        if self.transport.inline and core.use_kernel:
-            # the flush's one IoU launch, queued on the device ahead of
-            # the forward; its readback waits until the decision is back
-            with self._phase("iou_launch", self._h_iou_launch):
-                pending = core.launch_iou(imgs)
+        seg = self.transport.segment(self.pool, clock)
+        # the flush's IoU launch, queued on the device ahead of the
+        # forward; its readback waits until the decision is back
+        with self._phase("iou_launch", self._h_iou_launch):
+            pending = self.transport.launch_iou(seg, imgs)
         with self._phase("policy", self._h_policy,
                          cpu_hist=self._h_policy_cpu):
             actions = self._decide(imgs, clock)
+        ious = None
         if pending is not None:
             with self._phase("iou_launch", self._h_iou_launch):
                 ious = pending.matrices()
         if self._obs_on:
             self._h_providers.observe_batch(
                 (np.asarray(actions) > 0.5).sum(axis=1).tolist())
-            if pending is not None:
-                self._c_iou_launched.inc(pending.launched)
         with self._cv:      # counters race with reset_stats() otherwise
             if pending is not None:
-                self._stat["iou_launches"].inc()
+                self._count_iou(pending.launched, 0)
             self._stat["flushes"].inc()
             self._stat[reason].inc()
             self._stat["requests"].inc(len(batch))
@@ -428,57 +404,53 @@ class AsyncFederationService:
             (span.trace_id, span.span_id)
         log_ctx = None
         if self.obs is not None and self.obs.serving_log is not None:
-            seg = None if self.pool is None else \
+            seg_idx = None if self.pool is None else \
                 int(self.pool.schedule.segment_index(clock))
-            log_ctx = {"seg": seg, "clock": int(clock), "reason": reason,
-                       "backend": self.shard_backend, "costs": costs}
+            log_ctx = {"seg": seg_idx, "clock": int(clock),
+                       "reason": reason, "backend": self.shard_backend}
         # fan out by home shard; the dispatcher does NOT wait — ensemble
         # assembly overlaps the next flush's agent forward
-        if not self.transport.inline:
-            # routing/accounting math stays in the parent (one vectorized
-            # pass); only (image, mask) rows cross the transport boundary
-            acts, n_sel, masks, cost, lat = self._svc._route_batch(
-                imgs, actions, costs=costs, latency_ms=lats)
-            for sid, positions in self._partition(imgs).items():
-                self._shard_pools[sid].submit(
-                    self._account_shard_mp, sid,
-                    [batch[p] for p in positions], positions, snapshot,
-                    acts, n_sel, masks, cost, lat, trace_ctx, log_ctx,
-                    time.perf_counter() if self._obs_on else 0.0)
-        else:
-            for sid, positions in self._partition(imgs).items():
-                self._shard_pools[sid].submit(
-                    self._account_shard, core, sid,
-                    [batch[p] for p in positions], actions[positions],
-                    costs, lats, trace_ctx, log_ctx,
-                    time.perf_counter() if self._obs_on else 0.0, ious)
+        for sid, positions in self._partition(imgs).items():
+            self._shard_pools[sid].submit(
+                self._account_shard, seg, sid,
+                [batch[p] for p in positions], actions[positions],
+                costs, lats, trace_ctx, log_ctx,
+                time.perf_counter() if self._obs_on else 0.0, ious)
 
     def _partition(self, imgs: np.ndarray):
         groups: dict = {}
-        route = (self.core.shard_id if self.transport.inline
-                 else self.transport.route)
+        route = self.transport.route
         for pos, img in enumerate(imgs):
-            groups.setdefault(route(int(img)), []).append(pos)
+            groups.setdefault(route(img), []).append(pos)
         return groups
 
-    def _trace_parent(self, trace_ctx):
-        """The (trace_id, parent_span_id) a shard-side span hangs off —
-        ``(None, None)`` when this flush carries no sampled request."""
-        if self._tracer is None or trace_ctx is None:
-            return None, None
-        return trace_ctx
+    def _count_iou(self, launched: int, used: int) -> None:
+        """An IoU launch the service learns of (the flush's, or a shard
+        part's for images the flush's lacked): ``launched`` entries
+        computed, ``used`` entries the tables built took.  ``_cv``'s lock
+        is re-entrant, so the dispatcher counts under its own hold."""
+        if launched:
+            with self._cv:
+                self._stat["iou_launches"].inc()
+        if self._obs_on:
+            self._c_iou_launched.inc(launched)
+            self._c_iou_used.inc(used)
 
-    def _account_shard(self, core, sid: int, items, actions: np.ndarray,
+    def _account_shard(self, seg, sid: int, items, actions: np.ndarray,
                        costs, lats, trace_ctx=None, log_ctx=None,
                        t_submit: float = 0.0, ious=None) -> None:
         """Runs on shard ``sid``'s dedicated thread — the only thread that
-        ever writes that shard's dicts (for the flush's captured core).
-        The dispatcher only reads the table memo's membership, to choose
-        what the flush's launch covers; ``ious`` is that launch's host
-        matrices by image id.  An image whose table was in flight when the
-        dispatcher looked is delivered twice and built once; one that the
-        launch lacks is launched for here."""
-        tid, parent = self._trace_parent(trace_ctx)
+        ever writes that shard's dicts (for the flush's segment ``seg``)
+        or talks to its worker/host.  The dispatcher only reads the table
+        memo's membership, to choose what the flush's launch covers;
+        ``ious`` is that launch's host matrices by image id.  An image
+        whose table was in flight when the dispatcher looked is delivered
+        twice and built once; one that the launch lacks is launched for
+        here.  A dead worker fails this shard's futures cleanly (the
+        socket plane first requeues to surviving hosts) — other shards
+        and the dispatcher keep serving."""
+        # the flush span a sampled request's assembly hangs off
+        tid, parent = trace_ctx or (None, None)
         try:
             if self._obs_on:
                 self._h_shard_wait.observe(
@@ -486,68 +458,22 @@ class AsyncFederationService:
             with self._phase("shard_assemble", None, tid, parent,
                              cpu_hist=self._h_assemble_cpu, shard=sid,
                              n=len(items)) as asm:
-                shard = core.shards[sid]
                 imgs = [it[0] for it in items]
-                # tables from the flush's launch; a launch here only for
-                # images it lacks
                 with self._phase("iou_table", self._h_iou_table, tid,
                                  asm.span_id):
-                    launched, used = shard.precompute(imgs, ious)
-                if launched:
-                    with self._cv:
-                        self._stat["iou_launches"].inc()
-                if self._obs_on and used:
-                    self._c_iou_launched.inc(launched)
-                    self._c_iou_used.inc(used)
+                    launched, used = self.transport.tables(seg, sid, imgs,
+                                                           ious)
+                self._count_iou(launched, used)
+                # a worker's eval span hangs off this assemble span
+                wire = None if asm.trace_id is None else \
+                    (asm.trace_id, asm.span_id)
                 with self._phase("fuse", None, tid, asm.span_id):
                     results = self._svc._account_batch(
-                        imgs, actions, core=shard, costs=costs,
-                        latency_ms=lats, log_ctx=log_ctx)
-            for (_, _, fut, _), res in zip(items, results):
-                fut.set_result(res)
-        except BaseException as e:
-            for _, _, fut, _ in items:
-                if not fut.done():
-                    fut.set_exception(e)
-
-    def _account_shard_mp(self, sid: int, items, positions,
-                          snapshot, acts, n_sel, masks, cost, lat,
-                          trace_ctx=None, log_ctx=None,
-                          t_submit: float = 0.0) -> None:
-        """RPC twin of ``_account_shard``: runs on shard ``sid``'s
-        parent-side thread, which owns that worker/host connection for
-        the duration (one batched RPC per flush per shard).  Accounting
-        was already routed in the dispatcher; only ensembles come back.
-        A dead worker fails this shard's futures cleanly (the socket
-        plane first requeues to surviving hosts) — other shards and the
-        dispatcher keep serving."""
-        tid, parent = self._trace_parent(trace_ctx)
-        try:
-            if self._obs_on:
-                self._h_shard_wait.observe(
-                    (time.perf_counter() - t_submit) * 1e3)
-            span = (self._tracer.span("shard_assemble", tid, parent=parent,
-                                      shard=sid, n=len(items))
-                    if tid is not None else NULL_SPAN)
-            with span:
-                imgs = [it[0] for it in items]
-                shard_masks = masks[positions]
-                # the worker's eval span hangs off THIS assemble span, so
-                # the assembled trace reads request -> flush ->
-                # shard_assemble -> worker_eval
-                wire = (self._tracer.wire_context(span)
-                        if tid is not None else None)
-                ens = self.transport.eval_batch(sid, imgs, shard_masks,
-                                                snapshot, trace=wire)
-                results = self._svc._results_from_ensembles(
-                    acts[positions], n_sel[positions], cost[positions],
-                    lat[positions], ens)
-                if log_ctx is not None:
-                    # the process plane never reaches _account_batch, so
-                    # the serving log is fed here (same record shape)
-                    self._svc._log_serving(
-                        imgs, [int(m) for m in shard_masks],
-                        log_ctx.get("costs"), results, log_ctx)
+                        imgs, actions,
+                        core=self.transport.local_core(seg, sid),
+                        ensembles=partial(self.transport.ensembles, seg,
+                                          sid, imgs, trace=wire),
+                        costs=costs, latency_ms=lats, log_ctx=log_ctx)
             for (_, _, fut, _), res in zip(items, results):
                 fut.set_result(res)
         except BaseException as e:
@@ -558,20 +484,13 @@ class AsyncFederationService:
     # -- cache invalidation ----------------------------------------------
     def invalidate_images(self, img_indices: Sequence[int]) -> int:
         """Drop the images' cached artifacts EVERYWHERE this service
-        could read them back: the live shard backend (all regimes on all
-        worker processes for the process backend) and, when a pool is
-        attached, every segment core the pool has materialized on the
-        parent side.  This is the one invalidation entry point callers
-        should use — invalidating only the pool (or only the core)
-        leaves the other side serving stale ensembles."""
-        dropped = 0
-        if self.pool is not None:
-            dropped += self.pool.invalidate_images(img_indices)
-            if self.transport.inline:
-                # the live sharded core is one of the pool's _sharded
-                # entries, already swept above
-                return dropped
-        return dropped + self.transport.invalidate(img_indices)
+        could read them back: the live shard plane (all regimes on all
+        worker processes or hosts) and, when a pool is attached, every
+        segment core the pool has materialized on the parent side.  This
+        is the one invalidation entry point callers should use —
+        invalidating only the pool (or only the core) leaves the other
+        side serving stale ensembles."""
+        return self.transport.invalidate(img_indices, self.pool)
 
     # -- lifecycle -------------------------------------------------------
     def close(self) -> None:
@@ -583,7 +502,7 @@ class AsyncFederationService:
         self._dispatcher.join()
         for pool in self._shard_pools:
             pool.shutdown(wait=True)
-        self.transport.close()      # reap workers/hosts (inline: no-op)
+        self.transport.close()      # reap workers/hosts (threads: no-op)
 
     def __enter__(self) -> "AsyncFederationService":
         return self
@@ -624,7 +543,7 @@ class AsyncFederationService:
     def extra_metric_snapshots(self) -> list:
         """Shard-side snapshots NOT already in the service's registry:
         each worker/host registry shipped back over the transport (RPC
-        planes) or the sharded core's hit/miss counters (inline).  Feed
+        planes) or the sharded core's hit/miss counters (threads).  Feed
         these to ``Obs.write_metrics`` — the obs registry itself is the
         service's registry, so only these extras need merging in."""
         return [self.transport.snapshot()]
@@ -633,7 +552,7 @@ class AsyncFederationService:
         """One merged counters/gauges/histograms snapshot for this
         service: its registry plus each shard's side of the story
         (worker/host registries over RPC, the sharded core's hit/miss
-        counters inline).  Plain dicts, mergeable with
+        counters on threads).  Plain dicts, mergeable with
         :func:`repro.obs.merge_snapshots`."""
         snaps = [self._metrics.snapshot()]
         if include_workers:

@@ -22,6 +22,7 @@ concurrent clients.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -67,10 +68,10 @@ class FederationService:
         segment's vectors (a down provider bills 0 and, if selected,
         costs its timeout latency).
 
-        This is the shard-merge contract shared by both shard backends:
+        This is the shard-merge contract shared by every shard plane:
         routing/accounting math happens here (parent side, vectorized),
         only the ensemble lookups go to a shard — a thread's dict or a
-        worker process's pipe.
+        worker's pipe.
         """
         costs = self.env.costs if costs is None else \
             np.asarray(costs, np.float32)
@@ -87,36 +88,16 @@ class FederationService:
                            self.transmission_ms * n_sel + inf_lat, 0.0)
         return acts, n_sel, masks, cost, latency
 
-    def _results_from_ensembles(self, acts: np.ndarray, n_sel: np.ndarray,
-                                cost: np.ndarray, latency: np.ndarray,
-                                ensembles: Sequence[Detections]
-                                ) -> List[FederationResult]:
-        """Assemble FederationResults from routed accounting + per-request
-        ensembles (memo lookups or worker-process rows — identical merge
-        either way).  The empty selection keeps its explicit zero-cost /
-        zero-latency route."""
-        out = []
-        for t, ens in enumerate(ensembles):
-            if n_sel[t] == 0:
-                # explicit empty route: nothing selected, nothing billed
-                out.append(FederationResult(Detections.empty(), acts[t],
-                                            0.0, 0.0))
-                continue
-            out.append(FederationResult(ens, acts[t], float(cost[t]),
-                                        float(latency[t])))
-        return out
-
     def _log_serving(self, imgs: Sequence[int], masks: Sequence[int],
                      costs_vec, results: List[FederationResult],
                      log_ctx: Optional[dict] = None, core=None) -> None:
-        """Append one serving-log record per request (both accounting
-        paths funnel here: ``_account_batch`` for the sync/thread plane,
-        ``_account_shard_mp`` for the process plane).
+        """Append one serving-log record per request (every serving path
+        funnels here through ``_account_batch``).
 
         When the flush was accounted on an in-process ``core``, AP50 is
         read off that core's memo/lattice (a dict or table hit on the
-        warm path) instead of rescored inside the log; the process plane
-        passes no core and the log scores against its own gts memo.
+        warm path) instead of rescored inside the log; RPC planes pass no
+        core and the log scores against its own gts memo.
         """
         obs = self.obs
         if obs is None or obs.serving_log is None:
@@ -134,25 +115,32 @@ class FederationService:
             backend=ctx.get("backend", "sync"), aps=aps)
 
     def _account_batch(self, imgs: Sequence[int], actions: np.ndarray,
-                       *, core=None, costs: Optional[np.ndarray] = None,
+                       *, core=None, ensembles=None,
+                       costs: Optional[np.ndarray] = None,
                        latency_ms: Optional[np.ndarray] = None,
                        log_ctx: Optional[dict] = None
                        ) -> List[FederationResult]:
-        """Vectorized ensemble + cost/latency bookkeeping for one flush.
+        """Route, ensembles, results and serving log for one flush, or
+        one shard's part of it: the one sequence every serving path runs.
 
-        ``core`` defaults to the env's shared cache — the async service
-        passes the request's home shard instead; only the memoized
-        ensemble lookups remain per-request.
+        ``ensembles(masks)`` answers the routed requests' ensembles (the
+        async service's transport: a shard's memo or its worker); without
+        it they are looked up in ``core``, the env's shared cache unless
+        given.  An in-process ``core`` is where the serving log reads
+        AP50.
         """
-        core = self.env.core if core is None else core
+        if ensembles is None:
+            core = self.env.core if core is None else core
+            ensembles = partial(core.ensembles, imgs)
         acts, n_sel, masks, cost, latency = self._route_batch(
             imgs, actions, costs=costs, latency_ms=latency_ms)
-        ensembles = [
-            Detections.empty() if n_sel[t] == 0
-            else core.ensemble(int(img), int(masks[t]))
-            for t, img in enumerate(imgs)]
-        results = self._results_from_ensembles(acts, n_sel, cost, latency,
-                                               ensembles)
+        # the empty selection keeps its explicit route: nothing selected,
+        # nothing billed
+        results = [
+            FederationResult(ens, acts[t], float(cost[t]),
+                             float(latency[t])) if n_sel[t]
+            else FederationResult(Detections.empty(), acts[t], 0.0, 0.0)
+            for t, ens in enumerate(ensembles(masks))]
         if self.obs is not None:
             self._log_serving(imgs, masks, costs, results, log_ctx,
                               core=core)

@@ -1,18 +1,13 @@
 """The shard transport seam: one protocol, a registry, three planes.
 
-``AsyncFederationService`` used to pick its evaluation plane through a
-``shard_backend="thread"|"process"`` string threaded through the
-constructor, the flush path, invalidation, metrics and close — adding a
-third plane meant touching every one of those branches.  This module
-puts the seam behind an object:
+``AsyncFederationService`` programs against :class:`ShardTransport`
+alone and never asks which plane it runs on.  This module holds:
 
-  * :class:`ShardTransport` — the protocol the service programs against:
-    ``route`` (image -> shard id), ``eval_batch`` (one batched RPC per
-    (flush, shard), the ``eval_on`` wire contract), ``invalidate``,
-    ``snapshot`` (shard-side metrics extras), ``close``, plus the
-    ``condemned`` status property and ``inline`` capability flag
-    (inline transports keep ensembles + accounting on parent threads;
-    RPC transports ship (image, mask) rows to shard workers/hosts).
+  * :class:`ShardTransport` — the protocol: per flush, its segment and
+    IoU launch; per shard part, its tables and ensembles; routing,
+    invalidation, metrics and close.  The base class answers as an RPC
+    plane does: (image, mask) rows go to shard workers or hosts, which
+    build their own tables.
   * a **registry** — transports self-register under their wire name;
     ``AsyncFederationService(transport="socket")`` resolves through
     :func:`get_transport`, so downstream planes (and tests) can register
@@ -22,14 +17,10 @@ puts the seam behind an object:
     process plane, and the H-host socket plane, all answering
     bit-identical rows (``tests/test_serving_socket.py`` holds the
     three-way parity).
-
-The legacy ``shard_backend=`` kwarg still works behind a
-``DeprecationWarning`` (resolved through this registry); see
-``docs/serving.md`` for the migration note.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Type
+from typing import Dict, List, Optional, Sequence, Tuple, Type
 
 from repro.ensemble.boxes import Detections
 
@@ -67,29 +58,35 @@ class ShardTransport:
       * ``route(img)`` — the image's home shard id in
         ``[0, n_shards)``; the service runs one parent-side accounting
         thread per shard id.
-      * ``eval_batch(sid, imgs, masks, snapshot, trace)`` — ensembles
-        for the rows, request order preserved; ``snapshot`` is a
-        picklable ``PoolSnapshot`` recipe scoping the rows to a scenario
-        segment; ``trace`` the wire trace context.  RPC transports may
-        REQUEUE rows to surviving shards when ``sid`` is condemned
-        mid-call.
-      * ``invalidate(imgs)`` — drop cached artifacts on every shard, all
-        regimes; returns entries dropped.
+      * ``segment(pool, clock)`` — what a flush at scenario ``clock``
+        evaluates against, handed back to every call below.
+      * ``launch_iou(seg, imgs)`` — the flush's IoU launch, or ``None``.
+      * ``tables(seg, sid, imgs, ious)`` — build shard ``sid``'s tables
+        for its part of the flush from the launch's host matrices;
+        returns the IoU entries launched and used.
+      * ``ensembles(seg, sid, imgs, masks, trace)`` — ensembles for the
+        rows, request order preserved; ``trace`` is the wire trace
+        context.  RPC transports may REQUEUE rows to surviving shards
+        when ``sid`` is condemned mid-call.
+      * ``local_core(seg, sid)`` — shard ``sid``'s in-process core, where
+        the serving log reads AP50, or ``None``.
+      * ``invalidate(imgs, pool)`` — drop cached artifacts on every shard,
+        all regimes, and in ``pool``'s parent-side segment cores; returns
+        entries dropped, each counted once.
       * ``snapshot()`` — shard-side metrics as one plain-dict snapshot
         (:func:`repro.obs.metrics.merge_snapshots`-compatible): what the
         parent's registry does NOT already hold.
       * ``condemned`` — shard ids permanently failed (never reused).
-      * ``inline`` — True when ensembles run on the parent's own shard
-        threads (the service then calls ``core.shards[sid]`` directly
-        and accounting touches the core; ``eval_batch`` stays unused).
 
-    ``core`` stays public: the underlying sharded evaluation core, for
-    surfaces the protocol deliberately does not wrap (tests, benches,
-    ``precompute`` warm-up).
+    The defaults are an RPC plane's: the segment is a picklable
+    ``PoolSnapshot`` recipe (``None`` without a pool), nothing is
+    launched or tabled in the parent, and ensembles come from the core's
+    ``eval_on``.  ``core`` stays public: the underlying sharded
+    evaluation core, for surfaces the protocol deliberately does not
+    wrap (tests, benches, ``precompute`` warm-up).
     """
 
     name = "?"
-    inline = False
 
     def __init__(self, core):
         self.core = core
@@ -113,13 +110,28 @@ class ShardTransport:
     def route(self, img_idx: int) -> int:
         return self.core.shard_id(int(img_idx))
 
-    def eval_batch(self, sid: int, imgs: Sequence[int],
-                   masks: Sequence[int], snapshot=None,
-                   trace=None) -> List[Detections]:
-        return self.core.eval_on(sid, imgs, masks, snapshot, trace=trace)
+    def segment(self, pool, clock: int):
+        # the worker/host pool persists across segments; the segment
+        # rides along with each shard request as a recipe
+        return None if pool is None else pool.snapshot_at(clock)
 
-    def invalidate(self, img_indices: Sequence[int]) -> int:
-        return self.core.invalidate_images(img_indices)
+    def launch_iou(self, seg, imgs: Sequence[int]):
+        return None
+
+    def tables(self, seg, sid: int, imgs: Sequence[int],
+               ious) -> Tuple[int, int]:
+        return 0, 0
+
+    def ensembles(self, seg, sid: int, imgs: Sequence[int],
+                  masks: Sequence[int], trace) -> List[Detections]:
+        return self.core.eval_on(sid, imgs, masks, seg, trace=trace)
+
+    def local_core(self, seg, sid: int):
+        return None
+
+    def invalidate(self, img_indices: Sequence[int], pool) -> int:
+        dropped = 0 if pool is None else pool.invalidate_images(img_indices)
+        return dropped + self.core.invalidate_images(img_indices)
 
     def snapshot(self) -> dict:
         return self.core.metrics_snapshot()
@@ -139,16 +151,10 @@ class ShardTransport:
 @register_transport("thread")
 class ThreadTransport(ShardTransport):
     """In-process shards (``ShardedSubsetEvaluationCore``): zero IPC,
-    ensembles serialize on the GIL.  Inline — the service's shard
-    threads touch ``core.shards[sid]`` directly and do their own
-    accounting, so ``eval_batch`` is never called on this transport."""
-
-    inline = True
-
-    def __init__(self, core, pool=None, workers: int = 0):
-        super().__init__(core)
-        self._pool = pool
-        self._workers = workers or core.n_shards
+    ensembles serialize on the GIL.  A flush's segment is the pool's
+    sharded core at its clock; on the kernel path the flush makes one IoU
+    launch, and the shard threads build tables from its host matrices
+    and look ensembles up in their own shard's memo."""
 
     @classmethod
     def build(cls, *, env=None, pool=None, workers: int = 2,
@@ -157,17 +163,36 @@ class ThreadTransport(ShardTransport):
         from repro.federation.evaluation import \
             ShardedSubsetEvaluationCore
         if pool is not None:
-            return cls(pool.sharded_core_at(0, workers), pool, workers)
-        return cls(ShardedSubsetEvaluationCore.like(env.core, workers),
-                   workers=workers)
+            return cls(pool.sharded_core_at(0, workers))
+        return cls(ShardedSubsetEvaluationCore.like(env.core, workers))
 
-    def core_at(self, clock: int):
+    def segment(self, pool, clock: int):
         """The pool's sharded core for this flush's segment (warm,
         memoized pool-side); updates ``self.core`` so routing follows the
-        live segment.  Identity without a pool."""
-        if self._pool is not None:
-            self.core = self._pool.sharded_core_at(clock, self._workers)
+        live segment.  The core itself without a pool."""
+        if pool is not None:
+            self.core = pool.sharded_core_at(clock, self.n_shards)
         return self.core
+
+    def launch_iou(self, seg, imgs: Sequence[int]):
+        return seg.launch_iou(imgs)
+
+    def tables(self, seg, sid: int, imgs: Sequence[int],
+               ious) -> Tuple[int, int]:
+        return seg.shards[sid].precompute(imgs, ious)
+
+    def ensembles(self, seg, sid: int, imgs: Sequence[int],
+                  masks: Sequence[int], trace) -> List[Detections]:
+        return seg.shards[sid].ensembles(imgs, masks)
+
+    def local_core(self, seg, sid: int):
+        return seg.shards[sid]
+
+    def invalidate(self, img_indices: Sequence[int], pool) -> int:
+        if pool is not None:
+            # the live sharded core is one of the pool's, swept there
+            return pool.invalidate_images(img_indices)
+        return self.core.invalidate_images(img_indices)
 
     def snapshot(self) -> dict:
         from repro.obs.metrics import counters_snapshot

@@ -1,8 +1,8 @@
 """The thread plane's one IoU launch per flush: the dispatcher launches
 for the flush's untabled images across every shard, the shard threads
 build their tables from its host arrays, and the tables and served
-results equal those of the per-shard ``precompute`` path.  The kernel
-runs in interpret mode on the CPU."""
+results equal those of the per-shard ``precompute`` path and of a
+one-image ``table()``.  The kernel runs in interpret mode on the CPU."""
 from __future__ import annotations
 
 import numpy as np
@@ -172,6 +172,39 @@ def test_rpc_planes_make_no_dispatcher_launch(transport, launches):
     host = ArmolEnv(tr, mode="gt", beta=0.0, seed=0, use_kernel=False)
     for g, r in zip(got, FederationService(host, agent).handle_many(IMGS)):
         _assert_results_equal(g, r)
+
+
+def test_one_image_table_is_one_padded_launch_equal_to_the_flush_table(
+        launches, monkeypatch):
+    """Kernel path: ``table()`` of one untabled image is one launch of
+    that image, padded by ``iou_launch_shape`` like a flush's, and its
+    IoU equals bit for bit the table a flush builds."""
+    shapes = []
+    spy = pipeline.launch_self_iou
+
+    def shaped(boxes_list):
+        result = spy(boxes_list)
+        shapes.append(tuple(result.shape))
+        return result
+
+    monkeypatch.setattr(pipeline, "launch_self_iou", shaped)
+    tr = generate_traces(default_providers(), 16, seed=4)
+    env = ArmolEnv(tr, mode="gt", beta=0.0, seed=0, use_kernel=True)
+    with _service(env, FixedAgent([1, 1, 0])) as svc:
+        svc.handle_many(IMGS)
+        flushed = svc.core
+    n0, s0 = len(launches), len(shapes)
+    core = ShardedSubsetEvaluationCore.like(env.core, W).shards[0]
+    for k, i in enumerate(IMGS):
+        got = core.table(i)
+        assert launches[n0:] == [1] * (k + 1)
+        b, m = pipeline.iou_launch_shape(1, len(got.boxes))
+        assert shapes[s0 + k] == (b, m, m)
+        want = flushed.shard_of(i)._tables[i]
+        assert got.iou.dtype == want.iou.dtype == np.float32
+        np.testing.assert_array_equal(got.iou, want.iou)
+    assert core.table(IMGS[0]) is core.table(IMGS[0])
+    assert len(launches) == n0 + len(IMGS)
 
 
 def test_in_flight_images_are_built_once_under_thread_churn():

@@ -68,14 +68,14 @@ def test_process_backend_obs_parity_and_artifacts(tmp_path):
             np.random.default_rng(0).integers(0, 30, 60)]
 
     with AsyncFederationService(env, agent, max_batch=1, workers=2,
-                                pool=pool, shard_backend="process") as s:
+                                pool=pool, transport="process") as s:
         bare = [s.handle(i) for i in reqs]
 
     d = str(tmp_path / "run")
     obs = Obs(d, trace_sample=1.0)
     obs.open_serving_log([p.name for p in PROVS], env.traces.gts)
     with AsyncFederationService(env, agent, max_batch=1, workers=2,
-                                pool=pool, shard_backend="process",
+                                pool=pool, transport="process",
                                 obs=obs) as s:
         inst = [s.handle(i) for i in reqs]
         extras = s.extra_metric_snapshots()

@@ -160,7 +160,7 @@ def test_async_service_process_backend_matches_sync_reference():
             np.random.default_rng(3).integers(0, len(TR), 40)]
     refs = [svc.handle(i) for i in imgs]
     with AsyncFederationService(ENV, agent, max_batch=8, workers=2,
-                                shard_backend="process") as asvc:
+                                transport="process") as asvc:
         got = asvc.handle_many(imgs)
         stats = dict(asvc.stats)
     for g, r in zip(got, refs):
@@ -179,7 +179,7 @@ def test_async_service_backends_bit_identical_under_concurrency():
         collected = [None] * len(streams)
         with AsyncFederationService(ENV, agent, max_batch=8, workers=2,
                                     max_wait_ms=1.0,
-                                    shard_backend=backend) as asvc:
+                                    transport=backend) as asvc:
             def client(k):
                 futs = [asvc.submit(i) for i in streams[k]]
                 collected[k] = [f.result() for f in futs]
@@ -198,7 +198,7 @@ def test_async_service_backends_bit_identical_under_concurrency():
 
 def test_process_backend_empty_selection_zero_cost():
     with AsyncFederationService(ENV, FixedAgent([0] * N), max_batch=4,
-                                workers=2, shard_backend="process") as asvc:
+                                workers=2, transport="process") as asvc:
         res = asvc.handle(5)
     assert len(res.detections) == 0
     assert res.cost_milli_usd == 0.0 and res.latency_ms == 0.0
@@ -206,7 +206,7 @@ def test_process_backend_empty_selection_zero_cost():
 
 def test_async_service_worker_death_fails_requests_cleanly():
     with AsyncFederationService(ENV, FixedAgent([1, 0, 0]), max_batch=4,
-                                workers=2, shard_backend="process") as asvc:
+                                workers=2, transport="process") as asvc:
         assert asvc.handle(0) is not None
         with asvc.core._locks[0]:
             asvc.core._conns[0].send((0, "crash"))
@@ -217,10 +217,41 @@ def test_async_service_worker_death_fails_requests_cleanly():
             ENV.costs[0]
 
 
+@pytest.mark.parametrize("transport", ["thread", "process"])
+def test_account_shard_fault_reaches_every_plane(transport, monkeypatch):
+    """Every plane assembles its shard parts in ``_account_shard``: the
+    benchmark's half-the-flush fault, patched there, fails the second
+    half of each shard's part on the thread and the process plane alike,
+    and the first half still answers as the synchronous service."""
+    orig = AsyncFederationService._account_shard
+
+    def half(self, core, sid, items, actions, *a, **k):
+        keep = (len(items) + 1) // 2
+        for _, _, fut, _ in items[keep:]:
+            fut.set_exception(RuntimeError("left out"))
+        return orig(self, core, sid, items[:keep], actions[:keep], *a, **k)
+
+    monkeypatch.setattr(AsyncFederationService, "_account_shard", half)
+    agent = FixedAgent([1, 0, 1])
+    imgs = list(range(8))
+    refs = FederationService(ENV, agent).handle_many(imgs)
+    # one full flush of eight; two shards of four, each keeping two
+    with AsyncFederationService(ENV, agent, max_batch=8,
+                                max_wait_ms=60_000.0, workers=2,
+                                transport=transport) as asvc:
+        futs = [asvc.submit(i) for i in imgs]
+        errs = [f.exception(timeout=60) for f in futs]
+        assert asvc.stats["flushes"] == 1
+    assert [i for i, e in zip(imgs, errs) if e is not None] == [4, 5, 6, 7]
+    assert all(str(e) == "left out" for e in errs[4:])
+    for f, r in zip(futs[:4], refs[:4]):
+        _assert_results_equal(f.result(), r)
+
+
 def test_bad_backend_rejected():
-    with pytest.raises(ValueError, match="shard_backend"):
+    with pytest.raises(ValueError, match="unknown shard transport"):
         AsyncFederationService(ENV, FixedAgent([1, 0, 0]),
-                               shard_backend="greenlet")
+                               transport="greenlet")
 
 
 def test_stale_reply_id_condemns_shard_never_misattributes():
@@ -281,7 +312,7 @@ def test_pool_swap_parity_thread_vs_process():
         # so both backends account request i under the SAME segment and
         # results must match bit for bit across every switch
         with AsyncFederationService(env, agent, max_batch=1, workers=2,
-                                    pool=pool, shard_backend=backend) as s:
+                                    pool=pool, transport=backend) as s:
             outs[backend] = [s.handle(i) for i in reqs]
             segs = pool.schedule.segment_index(s.clock - 1) + 1
     assert segs >= 2                        # the stream crossed a switch
@@ -304,7 +335,7 @@ def test_service_invalidate_reaches_worker_and_pool_caches():
     pool.core_at(0).ap50(3, 7)          # warm a parent-side segment core
     with AsyncFederationService(env, FixedAgent([1, 1, 1]), max_batch=1,
                                 workers=2, pool=pool,
-                                shard_backend="process") as svc:
+                                transport="process") as svc:
         before = svc.handle(3)
         assert svc.core.cache_sizes()["tables"] >= 1
         dropped = svc.invalidate_images([3])
@@ -350,7 +381,7 @@ def test_pool_snapshot_installs_once_per_worker_per_fingerprint():
                                 observe_pool=False, seed=1)
     with AsyncFederationService(env, FixedAgent([1, 1, 0]), max_batch=4,
                                 workers=2, pool=pool,
-                                shard_backend="process") as svc:
+                                transport="process") as svc:
         for i in range(80):
             svc.handle(i % 20)
         # price-war switches are economics-only: every segment shares ONE

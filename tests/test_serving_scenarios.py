@@ -55,7 +55,7 @@ def test_outage_regime_bills_zero_and_charges_timeout():
     start = pool.schedule.segment_range(down_seg)[0]
     with AsyncFederationService(env, FixedAgent(action), max_batch=1,
                                 workers=2, pool=pool,
-                                shard_backend="process") as svc:
+                                transport="process") as svc:
         svc.set_clock(int(start))
         res = svc.handle(3)
     assert res.cost_milli_usd == 0.0        # a down provider bills nothing
@@ -69,7 +69,7 @@ def test_revisited_regime_rehits_installed_snapshot():
     pool, env = _outage_setup()
     with AsyncFederationService(env, FixedAgent([1, 1, 0]), max_batch=1,
                                 workers=2, pool=pool,
-                                shard_backend="process") as svc:
+                                transport="process") as svc:
         for i in range(120):                # walk outage AND recovery
             svc.handle(i % 30)
         installed = [set(s) for s in svc.core._installed]
@@ -90,7 +90,7 @@ def test_benchmark_segment_aggregation_matches_accounting():
     action = np.asarray([1, 1, 1], np.float32)
     with AsyncFederationService(env, FixedAgent(action), max_batch=1,
                                 workers=2, pool=pool,
-                                shard_backend="process") as svc:
+                                transport="process") as svc:
         results = [svc.handle(i % 30) for i in range(120)]
     segs = np.asarray([sched.segment_index(i) for i in range(120)])
     cost = np.asarray([r.cost_milli_usd for r in results])

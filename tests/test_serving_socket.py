@@ -333,7 +333,7 @@ def test_http_door_rejects_malformed_submit():
             assert doc["cost_milli_usd"] == float(ENV.costs[0])
 
 
-# -- transport registry + deprecation --------------------------------------
+# -- transport registry -----------------------------------------------------
 
 def test_transport_registry_lists_and_resolves():
     names = available_transports()
@@ -366,18 +366,3 @@ def test_custom_transport_registers_and_serves():
     finally:
         from repro.serving import transports as _t
         _t._REGISTRY.pop("loopback-test", None)
-
-
-def test_shard_backend_kwarg_warns_but_works():
-    with pytest.warns(DeprecationWarning, match="shard_backend"):
-        asvc = AsyncFederationService(ENV, FixedAgent([1, 0, 0]),
-                                      max_batch=2, workers=2,
-                                      shard_backend="thread")
-    with asvc:
-        assert asvc.shard_backend == "thread"
-        assert asvc.handle(3).cost_milli_usd == float(ENV.costs[0])
-    # unknown legacy names still fail loudly (and mention the old kwarg)
-    with pytest.warns(DeprecationWarning):
-        with pytest.raises(ValueError, match="shard_backend"):
-            AsyncFederationService(ENV, FixedAgent([1, 0, 0]),
-                                   shard_backend="greenlet")

@@ -106,7 +106,7 @@ guarded_suite("test_selection*.py", "selector policy suite",
 # training or the process-shard backend must be slow-marked
 guarded_suite("test_obs*.py", "observability suite",
               require_slow_when=lambda src: "run_online" in src
-              or "shard_backend" in src)
+              or 'transport="process"' in src)
 if bad:
     sys.exit("optional dependency imported without a preceding "
              "pytest.importorskip guard (or serving/scenario test "
