@@ -28,8 +28,11 @@ What "agree" means in the serve phase: selected masks and billed costs
 are bit-identical; every IoU entry of the kernel is within ``IOU_ATOL`` of
 the twin's; the corpus AP50 of the served requests is within
 ``AP50_ATOL``; and where no IoU entry differs at all, every fused output
-must be bit-identical.  The counts of differing IoU entries and of
-requests whose fused output differs are printed either way.
+must have the reference's count and labels, and boxes and scores within
+``FUSE_ATOL`` (the thread plane fuses a flush's cold ensembles on the
+device, whose float32 sums run in another order than the host's).  The
+counts of differing IoU entries and of requests whose fused output
+differs at all are printed either way.
 
 Lines starting ``[smoke]`` are smoke-run readings, not benchmark
 numbers.  The last line of standard output is one JSON object:
@@ -58,6 +61,7 @@ TRAIN_KW = dict(images=400, epochs=2, steps_per_epoch=400, batch_size=256,
                 update_iters=50)
 IOU_ATOL = 1e-6
 AP50_ATOL = 1e-6
+FUSE_ATOL = 1e-6
 
 
 class SmokeFailure(RuntimeError):
@@ -133,6 +137,16 @@ def _same_dets(a, b) -> bool:
     return (np.array_equal(a.boxes, b.boxes)
             and np.array_equal(a.scores, b.scores)
             and np.array_equal(a.labels, b.labels))
+
+
+def _fused_gap(a, b) -> float:
+    """Largest box or score difference of two fused outputs; infinite
+    where their counts or labels differ."""
+    import numpy as np
+    if len(a) != len(b) or not np.array_equal(a.labels, b.labels):
+        return math.inf
+    return float(max(np.max(np.abs(a.boxes - b.boxes), initial=0.0),
+                     np.max(np.abs(a.scores - b.scores), initial=0.0)))
 
 
 def _corpus_ap50(results, reqs, traces) -> float:
@@ -212,19 +226,22 @@ def phase_serve(argv, compiles: CompileCounter, *, expect_kernel: bool):
             max_diff = max(max_diff, float(np.max(np.abs(got - want))))
     n_fused = sum(not _same_dets(a.detections, b.detections)
                   for a, b in zip(run.results, ref))
+    fuse_gap = max((_fused_gap(a.detections, b.detections)
+                    for a, b in zip(run.results, ref)), default=0.0)
     ap_k = _corpus_ap50(run.results, run.requests, traces)
     ap_r = _corpus_ap50(ref, run.requests, traces)
     say(f"serve: IoU entries differing kernel vs numpy twin: {n_iou} of "
         f"{n_entries} over {len(imgs)} images (max |diff| {max_diff!r}); "
         f"requests whose fused output differs: {n_fused} of "
-        f"{len(ref)}; corpus AP50 kernel={ap_k!r} twin={ap_r!r}")
+        f"{len(ref)} (max gap {fuse_gap!r}); corpus AP50 "
+        f"kernel={ap_k!r} twin={ap_r!r}")
     check(max_diff <= IOU_ATOL,
           f"kernel IoU differs from the twin by {max_diff} > {IOU_ATOL}")
     check(abs(ap_k - ap_r) <= AP50_ATOL,
           f"corpus AP50 differs by {abs(ap_k - ap_r)} > {AP50_ATOL}")
     if n_iou == 0:
-        check(n_fused == 0, f"IoU tables bit-identical but {n_fused} "
-              "fused outputs differ")
+        check(fuse_gap <= FUSE_ATOL, f"IoU tables bit-identical but a "
+              f"fused output differs by {fuse_gap} > {FUSE_ATOL}")
     n_c, s_c = compiles.between(t_start, time.perf_counter())
     say(f"serve: phase compiles={n_c} compile_s={s_c:.6g} "
         f"peak_bytes_in_use={peak_bytes()}")

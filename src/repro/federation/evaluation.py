@@ -193,11 +193,6 @@ class SubsetEvaluationCore:
             t = self._tables[img_idx]
         return t
 
-    def all_boxes(self, img_idx: int) -> np.ndarray:
-        """The image's boxes over all providers, in table row order."""
-        return np.concatenate(
-            [d.boxes for d in self.traces.dets[int(img_idx)]], axis=0)
-
     def precompute(self, img_indices: Sequence[int],
                    ious: Optional[Dict[int, np.ndarray]] = None
                    ) -> Tuple[int, int]:
@@ -287,12 +282,25 @@ class SubsetEvaluationCore:
         self._ens[key] = ens
         return ens
 
-    def ensembles(self, img_indices: Sequence[int],
-                  masks: Sequence[int]) -> List[Detections]:
+    def ensembles(self, img_indices: Sequence[int], masks: Sequence[int],
+                  fused: Optional["FusedRows"] = None) -> List[Detections]:
         """Memoized ensembles of (image, mask) rows, in order; an empty
-        mask answers empty without a lookup."""
-        return [self.ensemble(int(i), int(m)) if m else Detections.empty()
-                for i, m in zip(img_indices, masks)]
+        mask answers empty without a lookup.  ``fused`` (these rows of a
+        flush's device fuse) answers a row the memo lacks where the fuse
+        has it, and the memo keeps that answer."""
+        out = []
+        for k, (i, m) in enumerate(zip(img_indices, masks)):
+            i, m = int(i), int(m)
+            ens = None
+            if fused is not None and m and (i, m) not in self._ens:
+                ens = fused.detections(k, m)
+                if ens is not None:
+                    self.stats["ens_misses"] += 1
+                    self._ens[(i, m)] = ens
+            if ens is None:
+                ens = self.ensemble(i, m) if m else Detections.empty()
+            out.append(ens)
+        return out
 
     def pseudo_gt(self, img_idx: int) -> Detections:
         """Ensemble of ALL providers — the w/o-gt reference (paper Sec. III)."""
@@ -713,10 +721,12 @@ class SubsetEvaluationCore:
 class PendingIou:
     """A dispatched IoU launch over untabled images: the padded device
     result (its host copy already started) and, per image in launch
-    order, its id and box count."""
+    order, its id, box count and merged (boxes, scores, labels,
+    providers), which a device fuse over the launch reads."""
     imgs: List[int]
     counts: List[int]
     result: object          # (B, M, M) jax array
+    merged: List[Tuple[np.ndarray, ...]]
 
     @property
     def launched(self) -> int:
@@ -731,6 +741,60 @@ class PendingIou:
                 for k, (i, n) in enumerate(zip(self.imgs, self.counts))}
 
 
+@dataclass
+class PendingFuse:
+    """A dispatched device fuse over a flush
+    (``ShardedSubsetEvaluationCore.launch_fuse``): per flush row, its
+    image's slot in the IoU launch (-1 where the launch lacks the image)
+    and its subset mask; the (fused, count) device arrays, their host
+    copies started."""
+    slots: np.ndarray
+    masks: np.ndarray
+    result: Tuple[object, object]
+    _host: Optional[Tuple[np.ndarray, np.ndarray]] = None
+
+    def host(self) -> Tuple[np.ndarray, np.ndarray]:
+        """The result on the host; waits for the copy."""
+        if self._host is None:
+            self._host = tuple(np.asarray(a) for a in self.result)
+        return self._host
+
+    def part(self, positions: Sequence[int]) -> "FusedRows":
+        return FusedRows(self, list(positions))
+
+
+class FusedRows:
+    """One shard part's rows of a flush's device fuse, by their
+    ``positions`` in the flush; ``taken`` counts the answers handed out."""
+
+    def __init__(self, launch: PendingFuse, positions: List[int]):
+        self.launch = launch
+        self.positions = positions
+        self.taken = 0
+
+    def detections(self, k: int, mask: int) -> Optional[Detections]:
+        """Row ``k``'s fused detections for ``mask``, or ``None`` where
+        the fuse lacks them (the image was tabled, or another mask)."""
+        r = self.positions[k]
+        if self.launch.slots[r] < 0 or int(self.launch.masks[r]) != mask:
+            return None
+        fused, count = self.launch.host()
+        f = fused[r, :count[r]]
+        self.taken += 1
+        # copies: the memo must not hold the whole flush's result
+        return Detections.fast(np.ascontiguousarray(f[:, :4]),
+                               np.ascontiguousarray(f[:, 4]),
+                               f[:, 5].astype(np.int32),
+                               f[:, 6].astype(np.int32))
+
+
+def fuses_on_device(core) -> bool:
+    """Whether the flushes of a service on ``core`` fuse their cold
+    ensembles on the device: the kernel path, whose IoU is on the device
+    already, with WBF (NMS and Soft-NMS stay on the host)."""
+    return bool(core.use_kernel) and core.ablation == "wbf"
+
+
 def launch_untabled(core, imgs: Iterable[int]) -> Optional[PendingIou]:
     """The one IoU launch for ``imgs`` (untabled image ids, each launched
     once), padded with ``iou_launch_shape`` and dispatched without
@@ -743,11 +807,12 @@ def launch_untabled(core, imgs: Iterable[int]) -> Optional[PendingIou]:
     if not imgs:
         return None
     from repro.ensemble.pipeline import launch_self_iou
-    boxes = [core.all_boxes(i) for i in imgs]
-    result = launch_self_iou(boxes)
+    # full-set merges, in table row order
+    merged = [merge_provider_detections(core.traces.dets[i]) for i in imgs]
+    result = launch_self_iou([m[0] for m in merged])
     if result is None:
         return None
-    return PendingIou(imgs, [len(b) for b in boxes], result)
+    return PendingIou(imgs, [len(m[1]) for m in merged], result, merged)
 
 
 class ShardedSubsetEvaluationCore:
@@ -835,6 +900,45 @@ class ShardedSubsetEvaluationCore:
         return launch_untabled(self.shards[0], (
             int(i) for i in img_indices
             if int(i) not in self.shard_of(i)._tables))
+
+    def launch_fuse(self, pending: Optional[PendingIou],
+                    imgs: Sequence[int], actions: np.ndarray,
+                    n_rows: int) -> Optional[PendingFuse]:
+        """The device fuse of a flush's requests (``imgs``, ``actions``)
+        over its IoU launch ``pending``, rows padded to ``n_rows``,
+        dispatched without waiting; reads no memo.  ``None`` where
+        nothing is launched: no IoU launch (the numpy path, an RPC plane,
+        every image tabled), or not WBF."""
+        core = self.shards[0]
+        if pending is None or not fuses_on_device(core):
+            return None
+        from repro.ensemble import device_fuse
+        slot_of = {img: k for k, img in enumerate(pending.imgs)}
+        slots = np.asarray([slot_of.get(int(i), -1) for i in imgs],
+                           np.int64)
+        bits = np.asarray(actions, np.float32).reshape(len(slots), -1) > 0.5
+        result = device_fuse.launch_fuse(
+            pending.merged, pending.result, slots, bits, n_rows,
+            voting=core.voting, iou_thr=core.iou_thr)
+        masks = bits.astype(np.int64) @ np.left_shift(
+            np.int64(1), np.arange(bits.shape[1], dtype=np.int64))
+        return PendingFuse(slots, masks, result)
+
+    def warm_fuse(self, n_rows: int) -> int:
+        """Compile the device fuse for every launch shape a flush of
+        ``n_rows`` requests over these traces can reach; the number of
+        shapes, 0 where flushes fuse on the host."""
+        core = self.shards[0]
+        if not fuses_on_device(core):
+            return 0
+        from repro.ensemble import device_fuse
+        # aliased image ids share one per-provider list
+        lists = {id(d): d for d in self.traces.dets}.values()
+        max_boxes = max((sum(len(x) for x in d) for d in lists), default=0)
+        if not max_boxes:
+            return 0
+        return device_fuse.warm(n_rows, self.n_providers, n_rows, max_boxes,
+                                voting=core.voting, iou_thr=core.iou_thr)
 
     def ensemble(self, img_idx: int, mask: int) -> Detections:
         return self.shard_of(img_idx).ensemble(img_idx, mask)

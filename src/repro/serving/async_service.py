@@ -13,7 +13,9 @@ turns the service into an open system:
     flush) and, where the transport launches one (the thread plane's
     kernel path), ONE IoU launch for the flush's fresh images, issued by
     the dispatcher ahead of the forward, so the shard threads build
-    tables from host arrays.
+    tables from host arrays; with WBF, ONE device fuse of those images'
+    requests after the forward, whose rows the shard threads take into
+    their memo in place of grouping and fusing on the host.
   * **sharded caches behind a transport** — the subset-evaluation memo
     is split across W shared-nothing shards, each owned by one
     dispatcher-side thread.  The evaluation plane is pluggable
@@ -59,7 +61,8 @@ from repro.serving.transports import ShardTransport, get_transport
 # the dict-shaped stats contract: key order and names are part of the
 # public accessor (tests and benches read these directly)
 _STAT_KEYS = ("requests", "flushes", "batched_requests", "max_flush",
-              "flush_full", "flush_timeout", "flush_drain", "iou_launches")
+              "flush_full", "flush_timeout", "flush_drain", "iou_launches",
+              "fused_on_device")
 
 
 class AsyncFederationService:
@@ -163,7 +166,7 @@ class AsyncFederationService:
         self._phase = obs.phase if self._obs_on else no_phase
         self._h_policy = self._h_policy_cpu = self._h_shard_wait = \
             self._h_iou_table = self._h_assemble_cpu = \
-            self._h_iou_launch = None
+            self._h_iou_launch = self._h_fuse_launch = None
         if self._obs_on:
             self._h_flush_size = self._metrics.histogram(
                 "serving.flush_size",
@@ -182,6 +185,10 @@ class AsyncFederationService:
             # launches nothing observes the launch step alone)
             self._h_iou_launch = self._metrics.histogram(
                 "serving.iou_launch_ms")
+            # the dispatcher's device fuse of the flush (a flush that
+            # launches no IoU fuses nothing and observes nothing)
+            self._h_fuse_launch = self._metrics.histogram(
+                "serving.fuse_launch_ms")
             # handed to a shard thread -> that thread starts on it
             self._h_shard_wait = self._metrics.histogram(
                 "serving.shard_wait_ms")
@@ -206,6 +213,9 @@ class AsyncFederationService:
         # always land in the service's registry; worker-shipped spans
         # only when tracing is on (no-op for the thread transport)
         self.transport.bind_obs(self._metrics, self._tracer)
+        # every device-fuse shape a flush can reach, compiled before the
+        # first request (0 where flushes fuse on the host)
+        self.fuse_shapes = self.transport.warm_fuse(self.max_batch)
         self._shard_pools = [
             ThreadPoolExecutor(max_workers=1,
                                thread_name_prefix=f"fed-shard-{i}")
@@ -382,8 +392,13 @@ class AsyncFederationService:
         with self._phase("policy", self._h_policy,
                          cpu_hist=self._h_policy_cpu):
             actions = self._decide(imgs, clock)
-        ious = None
+        ious = fused = None
         if pending is not None:
+            # the flush's cold ensembles, queued behind the IoU launch
+            # whose result they read on the device; nobody waits here
+            with self._phase("fuse_launch", self._h_fuse_launch):
+                fused = self.transport.launch_fuse(seg, pending, imgs,
+                                                   actions, self.max_batch)
             with self._phase("iou_launch", self._h_iou_launch):
                 ious = pending.matrices()
         if self._obs_on:
@@ -415,7 +430,8 @@ class AsyncFederationService:
                 self._account_shard, seg, sid,
                 [batch[p] for p in positions], actions[positions],
                 costs, lats, trace_ctx, log_ctx,
-                time.perf_counter() if self._obs_on else 0.0, ious)
+                time.perf_counter() if self._obs_on else 0.0, ious,
+                None if fused is None else fused.part(positions))
 
     def _partition(self, imgs: np.ndarray):
         groups: dict = {}
@@ -438,17 +454,21 @@ class AsyncFederationService:
 
     def _account_shard(self, seg, sid: int, items, actions: np.ndarray,
                        costs, lats, trace_ctx=None, log_ctx=None,
-                       t_submit: float = 0.0, ious=None) -> None:
+                       t_submit: float = 0.0, ious=None,
+                       fused=None) -> None:
         """Runs on shard ``sid``'s dedicated thread — the only thread that
         ever writes that shard's dicts (for the flush's segment ``seg``)
         or talks to its worker/host.  The dispatcher only reads the table
         memo's membership, to choose what the flush's launch covers;
-        ``ious`` is that launch's host matrices by image id.  An image
-        whose table was in flight when the dispatcher looked is delivered
-        twice and built once; one that the launch lacks is launched for
-        here.  A dead worker fails this shard's futures cleanly (the
-        socket plane first requeues to surviving hosts) — other shards
-        and the dispatcher keep serving."""
+        ``ious`` is that launch's host matrices by image id, and
+        ``fused`` this part's rows of the flush's device fuse, which the
+        shard's memo takes where it lacks them (waiting for the fuse's
+        host copy).  An image whose table was in flight when the
+        dispatcher looked is delivered twice and built once; one that the
+        launch lacks is launched for here and fused on the host.  A dead
+        worker fails this shard's futures cleanly (the socket plane first
+        requeues to surviving hosts) — other shards and the dispatcher
+        keep serving."""
         # the flush span a sampled request's assembly hangs off
         tid, parent = trace_ctx or (None, None)
         try:
@@ -472,8 +492,12 @@ class AsyncFederationService:
                         imgs, actions,
                         core=self.transport.local_core(seg, sid),
                         ensembles=partial(self.transport.ensembles, seg,
-                                          sid, imgs, trace=wire),
+                                          sid, imgs, trace=wire,
+                                          fused=fused),
                         costs=costs, latency_ms=lats, log_ctx=log_ctx)
+                if fused is not None and fused.taken:
+                    with self._cv:
+                        self._stat["fused_on_device"].inc(fused.taken)
             for (_, _, fut, _), res in zip(items, results):
                 fut.set_result(res)
         except BaseException as e:

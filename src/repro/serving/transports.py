@@ -61,13 +61,19 @@ class ShardTransport:
       * ``segment(pool, clock)`` — what a flush at scenario ``clock``
         evaluates against, handed back to every call below.
       * ``launch_iou(seg, imgs)`` — the flush's IoU launch, or ``None``.
+      * ``launch_fuse(seg, pending, imgs, actions, n_rows)`` — the
+        flush's device fuse over that launch, or ``None``; each shard
+        part gets its rows of it (``part``).
+      * ``warm_fuse(n_rows)`` — compile every shape of that fuse; the
+        number of shapes.
       * ``tables(seg, sid, imgs, ious)`` — build shard ``sid``'s tables
         for its part of the flush from the launch's host matrices;
         returns the IoU entries launched and used.
-      * ``ensembles(seg, sid, imgs, masks, trace)`` — ensembles for the
-        rows, request order preserved; ``trace`` is the wire trace
-        context.  RPC transports may REQUEUE rows to surviving shards
-        when ``sid`` is condemned mid-call.
+      * ``ensembles(seg, sid, imgs, masks, trace, fused)`` — ensembles
+        for the rows, request order preserved; ``trace`` is the wire
+        trace context, ``fused`` the part's rows of the device fuse.
+        RPC transports may REQUEUE rows to surviving shards when ``sid``
+        is condemned mid-call.
       * ``local_core(seg, sid)`` — shard ``sid``'s in-process core, where
         the serving log reads AP50, or ``None``.
       * ``invalidate(imgs, pool)`` — drop cached artifacts on every shard,
@@ -80,8 +86,8 @@ class ShardTransport:
 
     The defaults are an RPC plane's: the segment is a picklable
     ``PoolSnapshot`` recipe (``None`` without a pool), nothing is
-    launched or tabled in the parent, and ensembles come from the core's
-    ``eval_on``.  ``core`` stays public: the underlying sharded
+    launched, fused or tabled in the parent, and ensembles come from the
+    core's ``eval_on``.  ``core`` stays public: the underlying sharded
     evaluation core, for surfaces the protocol deliberately does not
     wrap (tests, benches, ``precompute`` warm-up).
     """
@@ -118,12 +124,20 @@ class ShardTransport:
     def launch_iou(self, seg, imgs: Sequence[int]):
         return None
 
+    def launch_fuse(self, seg, pending, imgs: Sequence[int], actions,
+                    n_rows: int):
+        return None
+
+    def warm_fuse(self, n_rows: int) -> int:
+        return 0
+
     def tables(self, seg, sid: int, imgs: Sequence[int],
                ious) -> Tuple[int, int]:
         return 0, 0
 
     def ensembles(self, seg, sid: int, imgs: Sequence[int],
-                  masks: Sequence[int], trace) -> List[Detections]:
+                  masks: Sequence[int], trace,
+                  fused=None) -> List[Detections]:
         return self.core.eval_on(sid, imgs, masks, seg, trace=trace)
 
     def local_core(self, seg, sid: int):
@@ -153,8 +167,10 @@ class ThreadTransport(ShardTransport):
     """In-process shards (``ShardedSubsetEvaluationCore``): zero IPC,
     ensembles serialize on the GIL.  A flush's segment is the pool's
     sharded core at its clock; on the kernel path the flush makes one IoU
-    launch, and the shard threads build tables from its host matrices
-    and look ensembles up in their own shard's memo."""
+    launch and, with WBF, one device fuse of its cold ensembles over it;
+    the shard threads build tables from the launch's host matrices, take
+    the fuse's rows into their own shard's memo and look the rest up
+    there."""
 
     @classmethod
     def build(cls, *, env=None, pool=None, workers: int = 2,
@@ -177,13 +193,21 @@ class ThreadTransport(ShardTransport):
     def launch_iou(self, seg, imgs: Sequence[int]):
         return seg.launch_iou(imgs)
 
+    def launch_fuse(self, seg, pending, imgs: Sequence[int], actions,
+                    n_rows: int):
+        return seg.launch_fuse(pending, imgs, actions, n_rows)
+
+    def warm_fuse(self, n_rows: int) -> int:
+        return self.core.warm_fuse(n_rows)
+
     def tables(self, seg, sid: int, imgs: Sequence[int],
                ious) -> Tuple[int, int]:
         return seg.shards[sid].precompute(imgs, ious)
 
     def ensembles(self, seg, sid: int, imgs: Sequence[int],
-                  masks: Sequence[int], trace) -> List[Detections]:
-        return seg.shards[sid].ensembles(imgs, masks)
+                  masks: Sequence[int], trace,
+                  fused=None) -> List[Detections]:
+        return seg.shards[sid].ensembles(imgs, masks, fused)
 
     def local_core(self, seg, sid: int):
         return seg.shards[sid]

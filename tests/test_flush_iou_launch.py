@@ -2,17 +2,20 @@
 for the flush's untabled images across every shard, the shard threads
 build their tables from its host arrays, and the tables and served
 results equal those of the per-shard ``precompute`` path and of a
-one-image ``table()``.  The kernel runs in interpret mode on the CPU."""
+one-image ``table()``.  With WBF the dispatcher also launches one device
+fuse over it, whose rows the shard threads take into their memo.  The
+kernel runs in interpret mode on the CPU."""
 from __future__ import annotations
 
 import numpy as np
 import pytest
 
 from repro.core.sac import SAC, SACConfig
-from repro.ensemble import pipeline
+from repro.ensemble import device_fuse, pipeline
 from repro.federation.env import ArmolEnv
 from repro.federation.evaluation import ShardedSubsetEvaluationCore
-from repro.federation.providers import default_providers
+from repro.federation.providers import (default_providers,
+                                        scalability_providers)
 from repro.federation.traces import generate_traces
 from repro.serving.async_service import AsyncFederationService
 from repro.serving.federation_service import FederationService
@@ -33,6 +36,30 @@ class FixedAgent:
         if s.ndim == 2:
             return np.tile(self.action, (len(s), 1)), None
         return self.action.copy(), None
+
+
+class TableAgent:
+    """A selector policy: each image's action from a table."""
+
+    def __init__(self, actions):
+        self.actions = np.asarray(actions, np.float32)
+
+    def select_for_images(self, imgs):
+        return self.actions[np.asarray(imgs, np.int64)]
+
+
+@pytest.fixture
+def fuses(monkeypatch):
+    """Every device-fuse launch, by its request rows' image slots."""
+    calls = []
+    real = device_fuse.launch_fuse
+
+    def spy(merged, iou, slots, bits, n_rows, **kw):
+        calls.append(list(slots))
+        return real(merged, iou, slots, bits, n_rows, **kw)
+
+    monkeypatch.setattr(device_fuse, "launch_fuse", spy)
+    return calls
 
 
 @pytest.fixture
@@ -62,6 +89,20 @@ def _assert_results_equal(got, ref):
     for k in ("boxes", "scores", "labels"):
         np.testing.assert_array_equal(getattr(got.detections, k),
                                       getattr(ref.detections, k))
+
+
+def _assert_fused_equal(got, ref):
+    """The device fuse against the host path: the same groups, boxes and
+    scores to float32 summation order."""
+    np.testing.assert_array_equal(got.action, ref.action)
+    assert got.cost_milli_usd == ref.cost_milli_usd
+    assert got.latency_ms == ref.latency_ms
+    g, r = got.detections, ref.detections
+    assert len(g) == len(r)
+    np.testing.assert_array_equal(g.labels, r.labels)
+    np.testing.assert_array_equal(g.providers, r.providers)
+    np.testing.assert_allclose(g.boxes, r.boxes, rtol=0, atol=1e-6)
+    np.testing.assert_allclose(g.scores, r.scores, rtol=0, atol=1e-6)
 
 
 def _assert_tables_equal(core, ref, imgs):
@@ -155,10 +196,11 @@ def test_launch_reads_the_flush_segment_core(launches):
 
 
 @pytest.mark.parametrize("transport", ["process", "socket"])
-def test_rpc_planes_make_no_dispatcher_launch(transport, launches):
+def test_rpc_planes_make_no_dispatcher_launch(transport, launches, fuses):
     """The process and socket planes build their tables in their shard
-    workers or hosts, on the numpy twin: the dispatcher launches nothing
-    even where the service's environment asks for the kernel."""
+    workers or hosts, on the numpy twin: the dispatcher launches nothing,
+    IoU or fuse, even where the service's environment asks for the
+    kernel."""
     tr = generate_traces(default_providers(), 16, seed=4)
     env = ArmolEnv(tr, mode="gt", beta=0.0, seed=0, use_kernel=True)
     agent = FixedAgent([1, 0, 1])
@@ -167,8 +209,11 @@ def test_rpc_planes_make_no_dispatcher_launch(transport, launches):
                                 transport=transport) as svc:
         got = svc.handle_many(IMGS)
         stats = dict(svc.stats)
+        assert svc.fuse_shapes == 0
     assert stats["iou_launches"] == 0
+    assert stats["fused_on_device"] == 0
     assert len(launches) == n0
+    assert fuses == []
     host = ArmolEnv(tr, mode="gt", beta=0.0, seed=0, use_kernel=False)
     for g, r in zip(got, FederationService(host, agent).handle_many(IMGS)):
         _assert_results_equal(g, r)
@@ -264,3 +309,87 @@ def test_in_flight_images_are_built_once_under_thread_churn():
     for k, s in enumerate(streams):
         for g, r in zip(got[k], sync.handle_many(s)):
             _assert_results_equal(g, r)
+
+
+def test_flush_fuses_its_cold_requests_on_the_device(launches, fuses):
+    """Ten providers, kernel path, WBF: the flush's one IoU launch is
+    followed by one device fuse over its rows.  Every request whose image
+    the launch carried and whose mask is not empty takes its answer from
+    it (one image was tabled before the flush, so it is fused on the
+    host); the answers are the synchronous service's; the memo holds
+    them, so a second lookup, or a second flush, is a hit."""
+    tr = generate_traces(scalability_providers(), 16, seed=7,
+                         mean_objects=7.36)
+    env = ArmolEnv(tr, mode="gt", beta=0.0, seed=0, use_kernel=True)
+    rng = np.random.default_rng(2)
+    actions = (rng.random((16, env.n_providers)) < 0.4).astype(np.float32)
+    actions[IMGS[3]] = 0.0                      # an empty selection
+    agent = TableAgent(actions)
+    masks = {i: int(sum(1 << k for k in np.flatnonzero(actions[i])))
+             for i in IMGS}
+    tabled = IMGS[1]
+    with _service(env, agent) as svc:
+        assert svc.fuse_shapes > 0
+        core = svc.core
+        core.shard_of(tabled).precompute([tabled])
+        n0 = len(launches)
+        got = svc.handle_many(IMGS)
+        first = dict(svc.stats)
+        shard = core.shard_of(IMGS[0])
+        hits = shard.stats["ens_hits"]
+        memo = shard.ensemble(IMGS[0], masks[IMGS[0]])
+        assert shard.stats["ens_hits"] == hits + 1
+        assert memo is got[0].detections
+        again = svc.handle_many(IMGS)
+        second = dict(svc.stats)
+    assert launches[n0:] == [len(IMGS) - 1]
+    # one fuse: the tabled image has no slot, every other row has one
+    assert len(fuses) == 1
+    assert [s < 0 for s in fuses[0]] == [i == tabled for i in IMGS]
+    cold = [i for i in IMGS if i != tabled and masks[i]]
+    assert first["fused_on_device"] == len(cold) == len(IMGS) - 2
+    # all tabled now: the second flush launches and fuses nothing
+    assert second["fused_on_device"] == first["fused_on_device"]
+    assert len(fuses) == 1 and second["iou_launches"] == 1
+    sync = FederationService(env, agent).handle_many(IMGS)
+    for i, a, b, r in zip(IMGS, got, again, sync):
+        _assert_fused_equal(a, r)
+        _assert_results_equal(b, a)
+        if masks[i]:
+            assert b.detections is a.detections
+
+
+def test_nms_ablation_makes_no_fuse_launch(launches, fuses):
+    """NMS (and Soft-NMS) stay on the host: the flush still launches its
+    IoU, but nothing is fused on the device or warmed for it, and the
+    answers are the synchronous service's bit for bit."""
+    tr = generate_traces(default_providers(), 16, seed=4)
+    env = ArmolEnv(tr, mode="gt", beta=0.0, seed=0, ablation="nms",
+                   use_kernel=True)
+    agent = FixedAgent([1, 1, 1])
+    n0 = len(launches)
+    with _service(env, agent) as svc:
+        assert svc.fuse_shapes == 0
+        got = svc.handle_many(IMGS)
+        stats = dict(svc.stats)
+    assert launches[n0:] == [len(IMGS)]
+    assert stats["fused_on_device"] == 0 and fuses == []
+    for g, r in zip(got, FederationService(env, agent).handle_many(IMGS)):
+        _assert_results_equal(g, r)
+
+
+def test_fuse_warm_up_covers_every_flush_shape():
+    """The service compiles the device fuse for every launch shape its
+    flushes can reach, so serving compiles nothing more."""
+    tr = generate_traces(default_providers(), 16, seed=4)
+    env = ArmolEnv(tr, mode="gt", beta=0.0, seed=0, use_kernel=True)
+    counts = [sum(len(d) for d in dets) for dets in tr.dets]
+    want = {pipeline.iou_launch_shape(b, m) for b in range(1, 9)
+            for m in range(1, max(counts) + 1)}
+    with _service(env, FixedAgent([1, 0, 1])) as svc:
+        assert svc.fuse_shapes == len(want)
+        programs = device_fuse.fuse_flush._cache_size()
+        svc.handle_many(IMGS)
+        svc.handle_many([i + 1 for i in IMGS])
+        assert svc.stats["fused_on_device"] > 0
+        assert device_fuse.fuse_flush._cache_size() == programs

@@ -226,7 +226,8 @@ def test_stats_contract_unchanged_by_obs_registry():
     st_obs, z_obs = _stats(Obs(None))
     assert set(st_bare) == set(st_obs) == {
         "requests", "flushes", "batched_requests", "max_flush",
-        "flush_full", "flush_timeout", "flush_drain", "iou_launches"}
+        "flush_full", "flush_timeout", "flush_drain", "iou_launches",
+        "fused_on_device"}
     assert st_bare["requests"] == st_obs["requests"] == 24
     assert st_bare["batched_requests"] == st_obs["batched_requests"]
     assert all(v == 0 for v in z_bare.values())

@@ -34,6 +34,8 @@ TR = generate_traces(default_providers(), 20, seed=9)
 N = TR.n_providers
 ALL_MASKS = list(range(1, 1 << N))
 H = 3
+# hosts this module has killed, whether or not a call has surfaced it
+KILLED = set()
 
 
 @pytest.fixture(scope="module")
@@ -64,10 +66,13 @@ def test_socket_shards_match_unsharded_under_churn(pair, ops):
     for op in ops:
         if op[0] == "kill":
             # churn: condemn a host mid-stream (kept no-op at one
-            # survivor so the pool keeps serving for later examples)
-            healthy = cut.healthy_hosts()
-            if len(healthy) > 1:
-                victim = healthy[op[1] % len(healthy)]
+            # survivor so the pool keeps serving for later examples).
+            # A killed host stays healthy until a call touches it, so
+            # the survivors are the healthy hosts not killed here.
+            alive = [h for h in cut.healthy_hosts() if h not in KILLED]
+            if len(alive) > 1:
+                victim = alive[op[1] % len(alive)]
+                KILLED.add(victim)
                 os.kill(cut.host_pids()[victim], signal.SIGKILL)
                 # first touch surfaces the death; eval_on requeues, so
                 # correctness below never depends on when it lands

@@ -123,3 +123,20 @@ def test_sac_act_and_update_block_compile_at_paper_widths(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < 16e9          # fits one v5e chip's 16 GB
+
+
+def test_device_fuse_compiles_at_flush_shapes(one_chip):
+    """The device fuse of a flush of 16 request rows over its IoU launch:
+    the smallest and largest launch shapes of the Tab. II roster (3
+    providers, up to 40 boxes) and the largest of the Tab. III one (10
+    providers, 96 boxes) under every voting."""
+    from repro.ensemble import device_fuse
+    for n, b, m, votings in ((3, 2, 8, ("affirmative",)),
+                             (3, 16, 40, ("affirmative",)),
+                             (10, 16, 96, device_fuse.VOTING)):
+        for voting in votings:
+            device_fuse.fuse_flush.lower(
+                _spec((b, m, device_fuse.TABLE_COLS), one_chip),
+                _spec((b, m, m), one_chip),
+                _spec((16, 1 + n), one_chip, jnp.int32),
+                voting=voting, thr=0.5).compile()
